@@ -143,7 +143,12 @@ def main() -> None:
         ap.error("--nightly only applies to --manifest runs")
 
     if args.manifest:
+        # the parent stays off JAX: each suite's child owns the device
         raise SystemExit(_run_manifest(args.manifest, nightly=args.nightly))
+
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
 
     if args.list_scenarios:
         from repro import scenarios

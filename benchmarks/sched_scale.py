@@ -15,14 +15,14 @@
 6. On-device RL training throughput (Anakin-style, transitions/s).
 7. Seed-parallel training: `train_and_select`'s candidates as ONE vmapped,
    mesh-sharded launch vs the sequential Python seed loop it replaced.
-   Runs in a child process with the host platform split into
-   ``min(cpu_count, n_seeds)`` devices so the engine's seed-axis sharding
-   is actually exercised on CPU; on a real accelerator mesh the same code
-   shards over the ``data`` axis.
+   Runs in a child process pinned to the CPU (``JAX_PLATFORMS=cpu``) with
+   the host platform split into ``min(cpu_count, n_seeds)`` devices, so the
+   engine's seed-axis sharding is exercised on CPU virtual devices; rows
+   are prefixed ``cpu_``.
 8. Joint seed×env sharding: the 2-D ``("seed", "data")`` layout vs pure
-   seed sharding at ``n_seeds < n_devices`` (a force-split 4-device host,
-   where seed-only sharding's ceiling is 2 busy devices at n_seeds=2 and
-   the joint planner runs a (2, 2) grid over all 4).
+   seed sharding at ``n_seeds < n_devices`` (a force-split 4-device CPU
+   host, where seed-only sharding's ceiling is 2 busy devices at n_seeds=2
+   and the joint planner runs a (2, 2) grid over all 4); ``cpu_`` rows.
 9. Replay marginal cost: the fused-ring add + one-gather sample exactly as
    the training loop drives them — the residual per-seed cost the
    struct-of-arrays rework targets.
@@ -108,7 +108,6 @@ def fused_scoring() -> List[Tuple[str, float, float]]:
     """
     rows = []
     params = dqn.init_qnet(jax.random.PRNGKey(0))
-    mode = None if jax.default_backend() == "tpu" else "xla"
     for n in (4096, 16384, 131072):
         cfg = fleet_cluster(n)
         state = kenv.reset(jax.random.PRNGKey(0), cfg)
@@ -116,7 +115,7 @@ def fused_scoring() -> List[Tuple[str, float, float]]:
         unfused = jax.jit(lambda s, _cfg=cfg: ops.sdqn_score_afterstate(
             s, pod, _cfg, params, mode="ref"))
         fused = jax.jit(lambda s, _cfg=cfg: ops.sdqn_score_afterstate(
-            s, pod, _cfg, params, mode=mode))
+            s, pod, _cfg, params))
         dt_un = _time(unfused, state)
         dt_fu = _time(fused, state)
         rows.append((f"afterscore_unfused_n{n}", dt_un * 1e6, n / dt_un))
@@ -186,6 +185,18 @@ def training_throughput(smoke: bool = False) -> List[Tuple[str, float, float]]:
     return [("sdqn_train_ondevice", dt * 1e6, transitions / dt)]
 
 
+def _cpu_child_env(devices: int) -> dict:
+    """Environment of a measurement child: pinned to the CPU, split into
+    ``devices`` virtual devices.  The parent has already touched JAX, so on
+    an accelerator host it holds the device and a child could not get it;
+    these children measure CPU layouts by design."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
+                        f" --xla_force_host_platform_device_count={devices}").strip()
+    return env
+
+
 def _pick_seed_devices(n_seeds: int, cpus: int) -> int:
     """Largest divisor of ``n_seeds`` that fits the core count (the seed
     axis shards evenly or not at all)."""
@@ -225,10 +236,11 @@ def _seed_parallel_measurements(n_seeds: int, episodes: int) -> List[Tuple[str, 
     dt_par = _time(parallel, key, iters=3, warmup=1)
     per_seed = rl.episodes * rl.pods_per_episode * rl.n_envs
     return [
-        (f"seed_sequential_s{n_seeds}", dt_seq * 1e6, n_seeds * per_seed / dt_seq),
-        (f"seed_parallel_s{n_seeds}_d{n_dev}", dt_par * 1e6,
+        (f"cpu_seed_sequential_s{n_seeds}", dt_seq * 1e6,
+         n_seeds * per_seed / dt_seq),
+        (f"cpu_seed_parallel_s{n_seeds}_d{n_dev}", dt_par * 1e6,
          n_seeds * per_seed / dt_par),
-        ("seed_parallel_speedup", 0.0, dt_seq / dt_par),
+        ("cpu_seed_parallel_speedup", 0.0, dt_seq / dt_par),
     ]
 
 
@@ -244,13 +256,10 @@ def seed_parallel_speedup(n_seeds: int = 4, episodes: int = 20) -> List[Tuple[st
     n_seeds multiple.
     """
     devices = _pick_seed_devices(n_seeds, os.cpu_count() or 1)
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        f" --xla_force_host_platform_device_count={devices}").strip()
     out = subprocess.run(
         [sys.executable, "-m", "benchmarks.sched_scale",
          "--seed-parallel-child", str(n_seeds), str(episodes)],
-        env=env, capture_output=True, text=True)
+        env=_cpu_child_env(devices), capture_output=True, text=True)
     if out.returncode != 0:
         raise RuntimeError(
             f"seed-parallel child failed ({out.returncode}):\n{out.stderr}")
@@ -301,11 +310,11 @@ def _joint_sharding_measurements(n_seeds: int, episodes: int) -> List[Tuple[str,
     dt_joint = _time(joint, key, iters=3, warmup=1)
     per_seed = rl.episodes * rl.pods_per_episode * rl.n_envs
     return [
-        (f"seedonly_s{n_seeds}_d{n_seed_dev}", dt_seed * 1e6,
+        (f"cpu_seedonly_s{n_seeds}_d{n_seed_dev}", dt_seed * 1e6,
          n_seeds * per_seed / dt_seed),
-        (f"joint_s{n_seeds}_d{n_dev}", dt_joint * 1e6,
+        (f"cpu_joint_s{n_seeds}_d{n_dev}", dt_joint * 1e6,
          n_seeds * per_seed / dt_joint),
-        ("joint_sharding_speedup", 0.0, dt_seed / dt_joint),
+        ("cpu_joint_sharding_speedup", 0.0, dt_seed / dt_joint),
     ]
 
 
@@ -321,13 +330,10 @@ def joint_sharding_speedup(n_seeds: int = 2, episodes: int = 20,
     container both programs time-share the same 2 cores and the ratio sits
     near 1x, which is why the committed gate floor is conservative.
     """
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        f" --xla_force_host_platform_device_count={devices}").strip()
     out = subprocess.run(
         [sys.executable, "-m", "benchmarks.sched_scale",
          "--joint-sharding-child", str(n_seeds), str(episodes)],
-        env=env, capture_output=True, text=True)
+        env=_cpu_child_env(devices), capture_output=True, text=True)
     if out.returncode != 0:
         raise RuntimeError(
             f"joint-sharding child failed ({out.returncode}):\n{out.stderr}")

@@ -9,9 +9,11 @@ clusters.  The actual sharded topology (the Anakin/Podracer pattern):
 
   * ``train(..., mesh=...)`` pins the ``n_envs`` environment batch to the
     mesh ``data`` axis with ``NamedSharding`` constraints — each device
-    steps its slice of the clusters, the replay write and the (replicated)
-    learner update are the only cross-device points, and XLA inserts the
-    one all-gather they need.  ``mesh=None`` (or an ``n_envs`` that does
+    steps its slice of the clusters (the per-env transition and bootstrap
+    scoring run under ``shard_map``, since the compiler cannot partition a
+    Pallas kernel), the replay write and the (replicated) learner update
+    are the only cross-device points, and XLA inserts the one all-gather
+    they need.  ``mesh=None`` (or an ``n_envs`` that does
     not divide the ``data`` axis) falls back to the single-device program
     unchanged, so CPU tests and the 1-device container run the same code.
   * ``repro.train.engine.train_seeds`` vmaps this whole program over the
@@ -195,6 +197,11 @@ def _bootstrap_bonus(online_params, target_params, env_state, pod, env_cfg,
     return jnp.where(jnp.any(ok), rl.gamma * q_tgt, 0.0)
 
 
+def _env_sharded(mesh, n_envs: int) -> bool:
+    return (mesh is not None and "data" in mesh.axis_names
+            and n_envs % mesh.shape["data"] == 0)
+
+
 def _env_constraint(mesh, n_envs: int):
     """Sharding-constraint applier for env-batched pytrees, or identity.
 
@@ -204,8 +211,7 @@ def _env_constraint(mesh, n_envs: int):
     (``mesh=None``, no ``data`` axis, indivisible batch) returns identity so
     the single-device program is untouched.
     """
-    if (mesh is None or "data" not in mesh.axis_names
-            or n_envs % mesh.shape["data"] != 0):
+    if not _env_sharded(mesh, n_envs):
         return lambda tree, time_leading=False: tree
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -214,6 +220,34 @@ def _env_constraint(mesh, n_envs: int):
         return jax.lax.with_sharding_constraint(tree, NamedSharding(mesh, spec))
 
     return constrain
+
+
+def _env_map(mesh, n_envs: int):
+    """``env_map(fn, batched, shared)``: ``fn(*batched[i], *shared)`` for
+    every env ``i``, stacked.
+
+    A ``vmap`` over the env axis.  When ``mesh`` shards envs over ``data``
+    (``_env_constraint``) it runs under ``shard_map``, each device mapping
+    its own envs: the per-env scoring holds Pallas kernels, which the
+    compiler cannot partition.  ``shared`` (params, scalars) is replicated.
+    """
+    sharded = _env_sharded(mesh, n_envs)
+
+    def env_map(fn, batched, shared=()):
+        def mapped(b, s):
+            return jax.vmap(lambda *xs: fn(*xs, *s))(*b)
+
+        if sharded:
+            P = jax.sharding.PartitionSpec
+            # check_vma=False: pallas_call's outputs carry no varying-axes
+            # type, and under train_seeds' vmap(spmd_axis_name="seed") the
+            # check also rejects the batched seed axis
+            mapped = jax.shard_map(mapped, mesh=mesh,
+                                   in_specs=(P("data"), P()),
+                                   out_specs=P("data"), check_vma=False)
+        return mapped(tuple(batched), tuple(shared))
+
+    return env_map
 
 
 def _make_episode_fn(env_cfg: EnvConfig, rl: RLConfig, n_steps_total: int,
@@ -230,6 +264,7 @@ def _make_episode_fn(env_cfg: EnvConfig, rl: RLConfig, n_steps_total: int,
     reward_fn = rewards.make_reward_fn(rl.variant, rl.consolidation_n,
                                        rl.efficiency_weight, rl.energy_weight)
     shard = _env_constraint(mesh, rl.n_envs)
+    env_map = _env_map(mesh, rl.n_envs)
     spec = policy_mod.get(rl.policy)
     # Python-level static: sequence specs (embed_dim > 0) thread per-env
     # encoder carries through the pod scan; stateless specs thread an empty
@@ -293,17 +328,19 @@ def _make_episode_fn(env_cfg: EnvConfig, rl: RLConfig, n_steps_total: int,
                 carries, embeds = jax.vmap(
                     spec.encode_step, in_axes=(None, 0, 0)
                 )(c.params, carries, wf)
-                new_states, stored, r, actions = jax.vmap(
-                    lambda kk, st, pod, dt, emb: _transition(
-                        kk, c.params, st, pod, dt, env_cfg, eps, reward_fn,
-                        spec=spec, embed=emb)
-                )(keys[: rl.n_envs], env_states, pod_t, dt_row, embeds)
+                new_states, stored, r, actions = env_map(
+                    lambda kk, st, pod, dt, emb, params, eps: _transition(
+                        kk, params, st, pod, dt, env_cfg, eps, reward_fn,
+                        spec=spec, embed=emb),
+                    (keys[: rl.n_envs], env_states, pod_t, dt_row, embeds),
+                    (c.params, eps))
             else:
-                new_states, stored, r, actions = jax.vmap(
-                    lambda kk, st, pod, dt: _transition(
-                        kk, c.params, st, pod, dt, env_cfg, eps, reward_fn,
-                        spec=spec)
-                )(keys[: rl.n_envs], env_states, pod_t, dt_row)
+                new_states, stored, r, actions = env_map(
+                    lambda kk, st, pod, dt, params, eps: _transition(
+                        kk, params, st, pod, dt, env_cfg, eps, reward_fn,
+                        spec=spec),
+                    (keys[: rl.n_envs], env_states, pod_t, dt_row),
+                    (c.params, eps))
             if use_ledger:
                 ledgers = jax.vmap(
                     lambda led, a, e, pod: kenv.ledger_record(led, t, a, e, pod)
@@ -322,17 +359,18 @@ def _make_episode_fn(env_cfg: EnvConfig, rl: RLConfig, n_steps_total: int,
                     _, embeds_next = jax.vmap(
                         spec.encode_step, in_axes=(None, 0, 0)
                     )(c.params, carries, wf_next)
-                    bonus = jax.vmap(
-                        lambda st, pod, emb: _bootstrap_bonus(
-                            c.params, c.target_params, st, pod, env_cfg, rl,
-                            spec=spec, embed=emb)
-                    )(new_states, pod_next_t, embeds_next)
+                    bonus = env_map(
+                        lambda st, pod, emb, params, target: _bootstrap_bonus(
+                            params, target, st, pod, env_cfg, rl, spec=spec,
+                            embed=emb),
+                        (new_states, pod_next_t, embeds_next),
+                        (c.params, c.target_params))
                 else:
-                    bonus = jax.vmap(
-                        lambda st, pod: _bootstrap_bonus(
-                            c.params, c.target_params, st, pod, env_cfg, rl,
-                            spec=spec)
-                    )(new_states, pod_next_t)
+                    bonus = env_map(
+                        lambda st, pod, params, target: _bootstrap_bonus(
+                            params, target, st, pod, env_cfg, rl, spec=spec),
+                        (new_states, pod_next_t),
+                        (c.params, c.target_params))
                 targets = r + jnp.where(t + 1 < rl.pods_per_episode, bonus, 0.0)
 
             # dropped arrivals (all-infeasible burst) store with weight 0:

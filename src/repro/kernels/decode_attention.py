@@ -14,14 +14,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -93,12 +86,10 @@ def decode_attention(
 
     grid = (b * hq, skv // block_k)
     scratch = [
-        jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        jax.ShapeDtypeStruct((1, d), jnp.float32),
+        pltpu.VMEM((1, 1), jnp.float32),
+        pltpu.VMEM((1, 1), jnp.float32),
+        pltpu.VMEM((1, d), jnp.float32),
     ]
-    if _VMEM is not None:
-        scratch = [_VMEM(s.shape, s.dtype) for s in scratch]
 
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, block_k=block_k),

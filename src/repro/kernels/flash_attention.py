@@ -13,14 +13,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific pallas helpers (present in jax>=0.4.31)
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover - CPU-only envs without the TPU module
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -106,16 +99,10 @@ def flash_attention(
         causal=causal, seq_q=sq, seq_k=skv,
     )
     scratch = [
-        jax.ShapeDtypeStruct((block_q, 1), jnp.float32),
-        jax.ShapeDtypeStruct((block_q, 1), jnp.float32),
-        jax.ShapeDtypeStruct((block_q, d), jnp.float32),
+        pltpu.VMEM((block_q, 1), jnp.float32),
+        pltpu.VMEM((block_q, 1), jnp.float32),
+        pltpu.VMEM((block_q, d), jnp.float32),
     ]
-    if _VMEM is not None:
-        scratch = [_VMEM(s.shape, s.dtype) for s in scratch]
-    compiler_params = None
-    if pltpu is not None and not interpret:
-        cp = getattr(pltpu, "CompilerParams", None) or getattr(pltpu, "TPUCompilerParams")
-        compiler_params = cp(dimension_semantics=("parallel", "parallel", "arbitrary"))
 
     out = pl.pallas_call(
         kernel,
@@ -129,6 +116,7 @@ def flash_attention(
         out_shape=jax.ShapeDtypeStruct((b * hq, sq, d), q.dtype),
         scratch_shapes=scratch,
         interpret=interpret,
-        **({"compiler_params": compiler_params} if compiler_params else {}),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(qr, kr, vr)
     return out.reshape(b, hq, sq, d).transpose(0, 2, 1, 3)

@@ -16,14 +16,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _scan_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, h0_ref,
@@ -79,9 +72,7 @@ def mamba_scan(
     assert di % block_d == 0 and s % block_s == 0
 
     grid = (bsz, di // block_d, s // block_s)
-    scratch = [jax.ShapeDtypeStruct((block_d, n), jnp.float32)]
-    if _VMEM is not None:
-        scratch = [_VMEM(sc.shape, sc.dtype) for sc in scratch]
+    scratch = [pltpu.VMEM((block_d, n), jnp.float32)]
 
     y, ht = pl.pallas_call(
         functools.partial(_scan_kernel, block_s=block_s, n_state=n),

@@ -141,7 +141,7 @@ def sdqn_score_afterstate(state, pod, cfg, params, *, mode: Optional[str] = None
     """
     from repro.core import env as kenv
 
-    mode = mode or ("pallas" if jax.default_backend() == "tpu" else "xla")
+    mode = mode or _default_mode()
     if mode == "ref":
         from repro.core import dqn
 
@@ -173,7 +173,7 @@ def sdqn_topk_afterstate(state, pod, cfg, params, *, k: int = 4,
     """
     from repro.core import env as kenv
 
-    mode = mode or ("pallas" if jax.default_backend() == "tpu" else "xla")
+    mode = mode or _default_mode()
     if mode == "ref":
         from repro.core import dqn
 
@@ -205,7 +205,7 @@ def sdqn_score_delta(cols, deltas, params, *, mode: Optional[str] = None,
     """
     from repro.core import env as kenv
 
-    mode = mode or ("pallas" if jax.default_backend() == "tpu" else "xla")
+    mode = mode or _default_mode()
     w1, b1, w2, b2 = _mlp_weights(params)
     if mode == "ref":
         feats = (jnp.stack(cols, axis=-1) + deltas[None, :]) / kenv.FEATURE_SCALE
@@ -231,7 +231,7 @@ def sdqn_topk_delta(cols, deltas, params, *, k: int = 4,
     """
     from repro.core import env as kenv
 
-    mode = mode or ("pallas" if jax.default_backend() == "tpu" else "xla")
+    mode = mode or _default_mode()
     w1, b1, w2, b2 = _mlp_weights(params)
     if mode == "ref":
         feats = (jnp.stack(cols, axis=-1) + deltas[None, :]) / kenv.FEATURE_SCALE

@@ -21,9 +21,11 @@ The paper's hot loop at fleet scale: score N candidate nodes through the
 Each kernel has a ``*_xla`` twin with identical arithmetic (broadcast
 multiply-accumulate, no (N, 6) stack, no GEMM) used as the fused fallback on
 CPU/GPU backends and as the reference for the interpret-mode sweeps.
-Per-node columns are viewed as (N // block_n, block_n) so each grid step
-streams ``block_n`` nodes through the lane dimension; weights and the
-scalar pack stay resident in VMEM/SMEM across the whole grid.
+The column kernels view each per-node column as (rows, 128) and stream
+(8·m, 128) tiles — the TPU's (8, 128) vreg tiling, so every block is dense
+in both sublanes and lanes.  The Q-net weights and the scalar pack ride in
+one SMEM vector, so the 6->H->1 MLP is unrolled as scalar-times-tile
+multiply-accumulates on the VPU.
 """
 from __future__ import annotations
 
@@ -32,18 +34,17 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _score_kernel(x_ref, w1_ref, b1_ref, w2_ref, b2_ref, o_ref):
+    # HIGHEST: float32 matmuls, as the reference computes; the TPU default
+    # rounds the operands to bfloat16 (~1e-2 off on unit-scale scores)
+    dot = functools.partial(jax.lax.dot, precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
     x = x_ref[...].astype(jnp.float32)           # (bn, F)
-    h = jax.lax.dot(x, w1_ref[...], preferred_element_type=jnp.float32)
-    h = jnp.maximum(h + b1_ref[...], 0.0)        # (bn, H)
-    q = jax.lax.dot(h, w2_ref[...], preferred_element_type=jnp.float32)
+    h = jnp.maximum(dot(x, w1_ref[...]) + b1_ref[...], 0.0)  # (bn, H)
+    q = dot(h, w2_ref[...])
     o_ref[...] = (q + b2_ref[...]).astype(o_ref.dtype)  # (bn, 1)
 
 
@@ -107,7 +108,7 @@ def _afterstate_norm_features(base_cpu, pods_cpu, startup_cpu, num_pods,
     ``s(i)`` reads scalar ``i`` of the pack.  Mirrors the placement delta of
     ``env.hypothetical_place`` + ``env._node_cpu_used`` + normalization
     exactly: one definition shared by the Pallas kernel body (operating on
-    (1, block_n) tiles) and the fused XLA twin (operating on (N,) columns).
+    (block_rows, 128) tiles) and the fused XLA twin (operating on (N,) columns).
     """
     start_cost = jnp.where(cached > 0.5, s(_S_WARM), s(_S_PULL))
     num_pods1 = num_pods + 1.0
@@ -129,42 +130,79 @@ def _afterstate_norm_features(base_cpu, pods_cpu, startup_cpu, num_pods,
     )
 
 
-def _afterstate_kernel(base_ref, pcpu_ref, scpu_ref, npod_ref, epod_ref,
-                       mem_ref, cached_ref, health_ref, up_ref, cap_ref,
-                       mcap_ref, mpod_ref, scal_ref, w1t_ref, b1_ref, w2_ref,
-                       o_ref):
+_LANES, _SUBLANES = 128, 8
+
+
+def _tiling(n: int, block_n: int):
+    """(block_rows, padded_rows) of the (rows, 128) column view.
+
+    ``block_n`` nodes per grid step, rounded to whole (8, 128) tiles and
+    capped at the padded column, so every block obeys the TPU's tiling rule.
+    """
+    rows = -(-n // _LANES)
+    cap = -(-rows // _SUBLANES) * _SUBLANES
+    block_rows = min(max(block_n // _LANES // _SUBLANES, 1) * _SUBLANES, cap)
+    return block_rows, -(-rows // block_rows) * block_rows
+
+
+def _grid_cols(cols, padded_rows, pad_value=0.0):
+    """Pad each (N,) column to ``padded_rows * 128`` and view it as
+    (padded_rows, 128)."""
+    out = []
+    for c in cols:
+        c = c.astype(jnp.float32)
+        pad_n = padded_rows * _LANES - c.shape[0]
+        if pad_n:
+            c = jnp.pad(c, (0, pad_n), constant_values=pad_value)
+        out.append(c.reshape(padded_rows, _LANES))
+    return out
+
+
+def _pack(scalars, w1, b1, w2):
+    """One (1, L) SMEM row: the scalar pack, then w1 (6, H) row-major, b1,
+    w2.  Two-dimensional so that under ``vmap`` the batched block
+    ``(squeezed, 1, L)`` still spans whole trailing dimensions."""
+    return jnp.concatenate([scalars.astype(jnp.float32), w1.reshape(-1),
+                            b1.reshape(-1), w2.reshape(-1)])[None, :]
+
+
+def _qnet(feats, pack_ref, n_hidden: int):
+    """6 -> H -> 1 MLP over six feature tiles, weights read from the pack.
+
+    Same multiply-accumulate order as the XLA twins: b1 + sum_f w1[f] x_f,
+    ReLU, then the w2 reduction over hidden units (b2 is added by callers).
+    """
+    w1_at, b1_at = _N_SCALARS, _N_SCALARS + 6 * n_hidden
+    w2_at = b1_at + n_hidden
+    q = None
+    for j in range(n_hidden):
+        h = pack_ref[0, b1_at + j]
+        for f in range(6):
+            h = h + pack_ref[0, w1_at + f * n_hidden + j] * feats[f]
+        t = jnp.maximum(h, 0.0) * pack_ref[0, w2_at + j]
+        q = t if q is None else q + t
+    return q
+
+
+def _col_spec(block_rows):
+    return pl.BlockSpec((block_rows, _LANES), lambda i: (i, 0))
+
+
+_SMEM_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _afterstate_kernel(n_hidden, base_ref, pcpu_ref, scpu_ref, npod_ref,
+                       epod_ref, mem_ref, cached_ref, health_ref, up_ref,
+                       cap_ref, mcap_ref, mpod_ref, pack_ref, o_ref):
     def s(i):
-        return scal_ref[0, i]
+        return pack_ref[0, i]
 
     feats = _afterstate_norm_features(
         base_ref[...], pcpu_ref[...], scpu_ref[...], npod_ref[...],
         epod_ref[...], mem_ref[...], cached_ref[...], health_ref[...],
         up_ref[...], cap_ref[...], mcap_ref[...], mpod_ref[...], s,
-    )  # six (1, bn) rows
-    w1t = w1t_ref[...]                               # (H, 6)
-    h = b1_ref[...]                                  # (H, 1) broadcasts
-    for f in range(6):
-        h = h + w1t[:, f:f + 1] * feats[f]           # (H, 1) * (1, bn)
-    q = jnp.sum(jnp.maximum(h, 0.0) * w2_ref[...], axis=0, keepdims=True)
-    o_ref[...] = q + s(_S_B2)
-
-
-def _grid_cols(cols, n, block_n, pad_value=0.0):
-    """Pad each (N,) column to a block multiple and view as (G, block_n)."""
-    pad_n = (-n) % block_n
-    out = []
-    for c in cols:
-        c = c.astype(jnp.float32)
-        if pad_n:
-            c = jnp.pad(c, (0, pad_n), constant_values=pad_value)
-        out.append(c.reshape(-1, block_n))
-    return out
-
-
-def _scalar_spec():
-    if pltpu is not None:
-        return pl.BlockSpec(memory_space=pltpu.SMEM)
-    return pl.BlockSpec((1, _N_SCALARS), lambda i: (0, 0))
+    )  # six (block_rows, 128) tiles
+    o_ref[...] = _qnet(feats, pack_ref, n_hidden) + s(_S_B2)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -184,25 +222,19 @@ def sdqn_score_afterstate(
     """Q-values (N,) for every candidate afterstate, features fused in-kernel."""
     n = node_cols[0].shape[0]
     h = w1.shape[1]
+    block_rows, rows = _tiling(n, block_n)
     # capacities pad with 1 so padded lanes stay finite (they are sliced off)
-    grids = _grid_cols(node_cols[:9], n, block_n) + _grid_cols(
-        node_cols[9:], n, block_n, pad_value=1.0)
-    g = grids[0].shape[0]
-    col_spec = pl.BlockSpec((1, block_n), lambda i: (i, 0))
+    grids = _grid_cols(node_cols[:9], rows) + _grid_cols(
+        node_cols[9:], rows, pad_value=1.0)
 
     out = pl.pallas_call(
-        _afterstate_kernel,
-        grid=(g,),
-        in_specs=[col_spec] * 12 + [
-            _scalar_spec(),
-            pl.BlockSpec((h, 6), lambda i: (0, 0)),
-            pl.BlockSpec((h, 1), lambda i: (0, 0)),
-            pl.BlockSpec((h, 1), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_n), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((g, block_n), jnp.float32),
+        functools.partial(_afterstate_kernel, h),
+        grid=(rows // block_rows,),
+        in_specs=[_col_spec(block_rows)] * 12 + [_SMEM_SPEC],
+        out_specs=_col_spec(block_rows),
+        out_shape=jax.ShapeDtypeStruct((rows, _LANES), jnp.float32),
         interpret=interpret,
-    )(*grids, scalars.reshape(1, _N_SCALARS), w1.T, b1.reshape(h, 1), w2)
+    )(*grids, _pack(scalars, w1, b1, w2))
     return out.reshape(-1)[:n]
 
 
@@ -234,15 +266,20 @@ def sdqn_score_afterstate_xla(node_cols: tuple, scalars: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 
-def _cols_kernel(c0, c1, c2, c3, c4, c5, scal_ref, w1t_ref, b1_ref, w2_ref,
-                 o_ref):
+def _cols_kernel(n_hidden, c0, c1, c2, c3, c4, c5, pack_ref, o_ref):
     cols = (c0, c1, c2, c3, c4, c5)
-    w1t = w1t_ref[...]                               # (H, 6), scale pre-folded
-    h = b1_ref[...]                                  # (H, 1)
-    for f in range(6):
-        h = h + w1t[:, f:f + 1] * (cols[f][...] + scal_ref[0, f])
-    q = jnp.sum(jnp.maximum(h, 0.0) * w2_ref[...], axis=0, keepdims=True)
-    o_ref[...] = q + scal_ref[0, 6]
+    feats = [cols[f][...] + pack_ref[0, f] for f in range(6)]
+    o_ref[...] = _qnet(feats, pack_ref, n_hidden) + pack_ref[0, 6]
+
+
+def _cols_pack(deltas, b2, w1n, b1, w2, ceilings=(0.0, 0.0, 0.0)):
+    """Pack layout of the column kernels: deltas at 0..5, b2 at 6, the
+    top-k feasibility ceilings at 7..9, then the weights (``_pack``)."""
+    scal = jnp.zeros((_N_SCALARS,), jnp.float32)
+    scal = scal.at[:6].set(deltas.astype(jnp.float32))
+    scal = scal.at[6].set(jnp.reshape(b2, ()))
+    scal = scal.at[7:10].set(jnp.asarray(ceilings, jnp.float32))
+    return _pack(scal, w1n, b1, w2)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -265,27 +302,17 @@ def sdqn_score_cols(
     """
     n = cols[0].shape[0]
     h = w1.shape[1]
-    grids = _grid_cols(cols, n, block_n)
-    g = grids[0].shape[0]
-    col_spec = pl.BlockSpec((1, block_n), lambda i: (i, 0))
-    scal = jnp.zeros((_N_SCALARS,), jnp.float32)
-    scal = scal.at[:6].set(deltas.astype(jnp.float32))
-    scal = scal.at[6].set(jnp.reshape(b2, ()))
-    w1n = w1 / scale[:, None]
+    block_rows, rows = _tiling(n, block_n)
+    grids = _grid_cols(cols, rows)
 
     out = pl.pallas_call(
-        _cols_kernel,
-        grid=(g,),
-        in_specs=[col_spec] * 6 + [
-            _scalar_spec(),
-            pl.BlockSpec((h, 6), lambda i: (0, 0)),
-            pl.BlockSpec((h, 1), lambda i: (0, 0)),
-            pl.BlockSpec((h, 1), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_n), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((g, block_n), jnp.float32),
+        functools.partial(_cols_kernel, h),
+        grid=(rows // block_rows,),
+        in_specs=[_col_spec(block_rows)] * 6 + [_SMEM_SPEC],
+        out_specs=_col_spec(block_rows),
+        out_shape=jax.ShapeDtypeStruct((rows, _LANES), jnp.float32),
         interpret=interpret,
-    )(*grids, scal.reshape(1, _N_SCALARS), w1n.T, b1.reshape(h, 1), w2)
+    )(*grids, _cols_pack(deltas, b2, w1 / scale[:, None], b1, w2))
     return out.reshape(-1)[:n]
 
 
@@ -314,70 +341,92 @@ def sdqn_score_cols_xla(cols: tuple, deltas: jnp.ndarray, scale: jnp.ndarray,
 _IDX_INF = 2**31 - 1
 
 
+def _tile_reduce(x, op):
+    """Reduce a (rows, 128) tile to (1, 1): over lanes, then sublanes."""
+    return op(op(x, axis=1, keepdims=True), axis=0, keepdims=True)
+
+
 def _iter_topk(scores, idx, k: int):
-    """k iterative (max, first-index) extractions over the last axis.
+    """k iterative (max, first-index) extractions over a whole tile.
 
     Ties break to the LOWEST index — exactly ``jnp.argmax``'s first-
-    occurrence rule, applied k times — so a hierarchical merge of these
-    candidates reproduces the flat argmax bit-for-bit.  Elementwise max /
-    where / min only (no sort, no gather), so the same definition runs
-    inside a Pallas TPU kernel body on (1, block_n) tiles and in the XLA
-    twins on (N,) columns.  Returns ((..., k) values, (..., k) indices);
-    exhausted positions carry ``-inf`` / ``_IDX_INF``.
+    occurrence rule, applied k times, and ``lax.top_k``'s order, ``-inf``
+    entries included — so a hierarchical merge of these candidates
+    reproduces the flat argmax bit-for-bit.  Elementwise max / where / min
+    only (no sort, no gather, no concatenate), so it lowers inside a Pallas
+    TPU kernel body.  Returns two lane-dense (1, 128) rows: candidate j in
+    lane j, lanes >= k carry ``-inf`` / ``_IDX_INF``.
     """
-    vals, ids = [], []
-    for _ in range(k):
-        m = jnp.max(scores, axis=-1, keepdims=True)
-        a = jnp.min(jnp.where(scores == m, idx, _IDX_INF), axis=-1,
-                    keepdims=True)
-        vals.append(m)
-        ids.append(a)
-        scores = jnp.where(idx == a, -jnp.inf, scores)
-    return jnp.concatenate(vals, axis=-1), jnp.concatenate(ids, axis=-1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+    vals = jnp.full((1, _LANES), -jnp.inf, jnp.float32)
+    ids = jnp.full((1, _LANES), _IDX_INF, jnp.int32)
+    left = idx >= 0                     # not yet extracted
+    for j in range(k):
+        m = _tile_reduce(jnp.where(left, scores, -jnp.inf), jnp.max)
+        a = _tile_reduce(jnp.where(left & (scores == m), idx, _IDX_INF),
+                         jnp.min)
+        vals = jnp.where(lane == j, m, vals)
+        ids = jnp.where(lane == j, a, ids)
+        left = left & (idx != a)
+    return vals, ids
 
 
 def _merge_topk(vals, idx, k: int):
-    """Merge (G, k) per-block candidates into the global (k,) top-k.
+    """Merge the per-block candidate rows into the global (k,) top-k.
 
-    ``lax.top_k`` over the block-major flatten keeps ties in ascending flat
-    position; blocks cover ascending index ranges and ``_iter_topk`` emits
-    within-block ties in ascending index, so the merged ties stay in
-    ascending GLOBAL index — the first-occurrence argmax rule survives the
-    hierarchy.  Same routine merges shard candidates in ``sched.shard``.
+    ``vals``/``idx`` are the kernels' (1, G * 128) lane-dense outputs; block
+    ``g``'s candidates sit in lanes ``[g * 128, g * 128 + k)``.  ``lax.top_k``
+    over the block-major flatten keeps ties in ascending flat position;
+    blocks cover ascending index ranges and ``_iter_topk`` emits within-block
+    ties in ascending index, so the merged ties stay in ascending GLOBAL
+    index — the first-occurrence argmax rule survives the hierarchy.  Same
+    rule merges shard candidates in ``sched.shard``.
     """
-    flat_v, flat_i = vals.reshape(-1), idx.reshape(-1)
+    flat_v = vals.reshape(-1, _LANES)[:, :k].reshape(-1)
+    flat_i = idx.reshape(-1, _LANES)[:, :k].reshape(-1)
     top_v, pos = jax.lax.top_k(flat_v, k)
     return top_v, flat_i[pos]
 
 
-def _afterstate_topk_kernel(k, base_ref, pcpu_ref, scpu_ref, npod_ref,
-                            epod_ref, mem_ref, cached_ref, health_ref, up_ref,
-                            cap_ref, mcap_ref, mpod_ref, creq_ref, mreq_ref,
-                            scal_ref, w1t_ref, b1_ref, w2_ref, ov_ref, oi_ref):
+def _global_idx(block_rows):
+    """(block_rows, 128) global node index of each slot of this grid step."""
+    shape = (block_rows, _LANES)
+    row = (pl.program_id(0) * block_rows
+           + jax.lax.broadcasted_iota(jnp.int32, shape, 0))
+    return row * _LANES + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+
+
+def _topk_specs(block_rows, rows):
+    """Out specs/shapes of the top-k kernels: one lane-dense (1, 128) row of
+    candidates per grid step."""
+    g = rows // block_rows
+    spec = pl.BlockSpec((1, _LANES), lambda i: (0, i))
+    return ([spec, spec],
+            [jax.ShapeDtypeStruct((1, g * _LANES), jnp.float32),
+             jax.ShapeDtypeStruct((1, g * _LANES), jnp.int32)])
+
+
+def _afterstate_topk_kernel(k, n_hidden, base_ref, pcpu_ref, scpu_ref,
+                            npod_ref, epod_ref, mem_ref, cached_ref,
+                            health_ref, up_ref, cap_ref, mcap_ref, mpod_ref,
+                            creq_ref, mreq_ref, pack_ref, ov_ref, oi_ref):
     def s(i):
-        return scal_ref[0, i]
+        return pack_ref[0, i]
 
     feats = _afterstate_norm_features(
         base_ref[...], pcpu_ref[...], scpu_ref[...], npod_ref[...],
         epod_ref[...], mem_ref[...], cached_ref[...], health_ref[...],
         up_ref[...], cap_ref[...], mcap_ref[...], mpod_ref[...], s,
     )
-    w1t = w1t_ref[...]
-    h = b1_ref[...]
-    for f in range(6):
-        h = h + w1t[:, f:f + 1] * feats[f]
-    q = jnp.sum(jnp.maximum(h, 0.0) * w2_ref[...], axis=0, keepdims=True)
-    q = q + s(_S_B2)                                 # (1, bn)
+    q = _qnet(feats, pack_ref, n_hidden) + s(_S_B2)
     # k8s filtering phase, in-kernel (env.feasible): padded lanes arrive with
     # healthy == 0 and capacity == 1, so they are masked right here
     ok = ((health_ref[...] > 0.5)
           & (creq_ref[...] + s(_S_CPU_REQ) <= cap_ref[...])
           & (mreq_ref[...] + s(_S_MEM_REQ) <= mcap_ref[...])
           & (npod_ref[...] < mpod_ref[...]))
-    bn = q.shape[-1]
-    gidx = (pl.program_id(0) * bn
-            + jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1))
-    vals, ids = _iter_topk(jnp.where(ok, q, -jnp.inf), gidx, k)
+    vals, ids = _iter_topk(jnp.where(ok, q, -jnp.inf),
+                           _global_idx(q.shape[0]), k)
     ov_ref[...] = vals
     oi_ref[...] = ids
 
@@ -399,35 +448,28 @@ def sdqn_score_afterstate_topk(
     """((k,) scores, (k,) indices): the shard's feasible top-k, in-kernel.
 
     Each grid step reduces its block to k candidates before anything leaves
-    the kernel, so HBM traffic is O(G * k) instead of O(N) — the full score
+    the kernel, so HBM traffic is O(G * 128) instead of O(N) — the full score
     vector never materializes.  Infeasible nodes score ``-inf``; an
     all-infeasible shard returns all ``-inf`` (the merge layer maps that to
     the NO_PLACEMENT sentinel).
     """
+    if not 1 <= k <= _LANES:
+        raise ValueError(f"k must be in [1, {_LANES}], got {k}")
     n = node_cols[0].shape[0]
     h = w1.shape[1]
-    block_n = max(min(block_n, n), k)
-    grids = _grid_cols(node_cols[:9], n, block_n) + _grid_cols(
-        node_cols[9:12], n, block_n, pad_value=1.0) + _grid_cols(
-        node_cols[12:], n, block_n)
-    g = grids[0].shape[0]
-    col_spec = pl.BlockSpec((1, block_n), lambda i: (i, 0))
+    block_rows, rows = _tiling(n, block_n)
+    grids = _grid_cols(node_cols[:9], rows) + _grid_cols(
+        node_cols[9:12], rows, pad_value=1.0) + _grid_cols(node_cols[12:], rows)
+    out_specs, out_shape = _topk_specs(block_rows, rows)
 
     vals, idx = pl.pallas_call(
-        functools.partial(_afterstate_topk_kernel, k),
-        grid=(g,),
-        in_specs=[col_spec] * 14 + [
-            _scalar_spec(),
-            pl.BlockSpec((h, 6), lambda i: (0, 0)),
-            pl.BlockSpec((h, 1), lambda i: (0, 0)),
-            pl.BlockSpec((h, 1), lambda i: (0, 0)),
-        ],
-        out_specs=[pl.BlockSpec((1, k), lambda i: (i, 0)),
-                   pl.BlockSpec((1, k), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((g, k), jnp.float32),
-                   jax.ShapeDtypeStruct((g, k), jnp.int32)],
+        functools.partial(_afterstate_topk_kernel, k, h),
+        grid=(rows // block_rows,),
+        in_specs=[_col_spec(block_rows)] * 14 + [_SMEM_SPEC],
+        out_specs=out_specs,
+        out_shape=out_shape,
         interpret=interpret,
-    )(*grids, scalars.reshape(1, _N_SCALARS), w1.T, b1.reshape(h, 1), w2)
+    )(*grids, _pack(scalars, w1, b1, w2))
     return _merge_topk(vals, idx, k)
 
 
@@ -451,35 +493,21 @@ def sdqn_score_afterstate_topk_xla(node_cols: tuple, scalars: jnp.ndarray,
     return jax.lax.top_k(jnp.where(ok, q, -jnp.inf), k)
 
 
-def _cols_topk_kernel(k, c0, c1, c2, c3, c4, c5, scal_ref, w1t_ref, b1_ref,
-                      w2_ref, ov_ref, oi_ref):
+def _cols_topk_kernel(k, n_hidden, c0, c1, c2, c3, c4, c5, pack_ref, ov_ref,
+                      oi_ref):
     cols = (c0, c1, c2, c3, c4, c5)
-    w1t = w1t_ref[...]
-    h = b1_ref[...]
-    for f in range(6):
-        h = h + w1t[:, f:f + 1] * (cols[f][...] + scal_ref[0, f])
-    q = jnp.sum(jnp.maximum(h, 0.0) * w2_ref[...], axis=0, keepdims=True)
-    q = q + scal_ref[0, 6]
+    feats = [cols[f][...] + pack_ref[0, f] for f in range(6)]
+    q = _qnet(feats, pack_ref, n_hidden) + pack_ref[0, 6]
     # PlacementEngine.feasible, in-kernel: healthy + post-delta ceilings on
-    # the cpu / mem / job-util percent columns (scalars 7..9)
+    # the cpu / mem / job-util percent columns (pack 7..9)
     ok = ((c3[...] > 0.5)
-          & (c0[...] + scal_ref[0, 0] <= scal_ref[0, 7])
-          & (c1[...] + scal_ref[0, 1] <= scal_ref[0, 8])
-          & (c2[...] + scal_ref[0, 2] <= scal_ref[0, 9]))
-    bn = q.shape[-1]
-    gidx = (pl.program_id(0) * bn
-            + jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1))
-    vals, ids = _iter_topk(jnp.where(ok, q, -jnp.inf), gidx, k)
+          & (feats[0] <= pack_ref[0, 7])
+          & (feats[1] <= pack_ref[0, 8])
+          & (feats[2] <= pack_ref[0, 9]))
+    vals, ids = _iter_topk(jnp.where(ok, q, -jnp.inf),
+                           _global_idx(q.shape[0]), k)
     ov_ref[...] = vals
     oi_ref[...] = ids
-
-
-def _cols_topk_scalars(deltas, b2, ceilings):
-    scal = jnp.zeros((_N_SCALARS,), jnp.float32)
-    scal = scal.at[:6].set(deltas.astype(jnp.float32))
-    scal = scal.at[6].set(jnp.reshape(b2, ()))
-    scal = scal.at[7:10].set(jnp.asarray(ceilings, jnp.float32))
-    return scal
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_n", "interpret"))
@@ -498,31 +526,23 @@ def sdqn_score_cols_topk(
     interpret: bool = False,
 ):
     """Per-shard feasible top-k of ``sdqn_score_cols``, reduced in-kernel."""
+    if not 1 <= k <= _LANES:
+        raise ValueError(f"k must be in [1, {_LANES}], got {k}")
     n = cols[0].shape[0]
     h = w1.shape[1]
-    block_n = max(min(block_n, n), k)
+    block_rows, rows = _tiling(n, block_n)
     # healthy (col 3) pads 0 -> infeasible; the rest pad 0 and stay finite
-    grids = _grid_cols(cols, n, block_n)
-    g = grids[0].shape[0]
-    col_spec = pl.BlockSpec((1, block_n), lambda i: (i, 0))
-    scal = _cols_topk_scalars(deltas, b2, ceilings)
-    w1n = w1 / scale[:, None]
+    grids = _grid_cols(cols, rows)
+    out_specs, out_shape = _topk_specs(block_rows, rows)
 
     vals, idx = pl.pallas_call(
-        functools.partial(_cols_topk_kernel, k),
-        grid=(g,),
-        in_specs=[col_spec] * 6 + [
-            _scalar_spec(),
-            pl.BlockSpec((h, 6), lambda i: (0, 0)),
-            pl.BlockSpec((h, 1), lambda i: (0, 0)),
-            pl.BlockSpec((h, 1), lambda i: (0, 0)),
-        ],
-        out_specs=[pl.BlockSpec((1, k), lambda i: (i, 0)),
-                   pl.BlockSpec((1, k), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((g, k), jnp.float32),
-                   jax.ShapeDtypeStruct((g, k), jnp.int32)],
+        functools.partial(_cols_topk_kernel, k, h),
+        grid=(rows // block_rows,),
+        in_specs=[_col_spec(block_rows)] * 6 + [_SMEM_SPEC],
+        out_specs=out_specs,
+        out_shape=out_shape,
         interpret=interpret,
-    )(*grids, scal.reshape(1, _N_SCALARS), w1n.T, b1.reshape(h, 1), w2)
+    )(*grids, _cols_pack(deltas, b2, w1 / scale[:, None], b1, w2, ceilings))
     return _merge_topk(vals, idx, k)
 
 

@@ -11,17 +11,24 @@ import dataclasses
 from typing import Optional
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with ``Auto`` axes: the engines pin layouts with
+    ``with_sharding_constraint``, which only accepts ``Auto`` mesh axes."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """A 1-device mesh with the same axis names (CPU tests / examples)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def make_train_mesh(n_data: int | None = None):
@@ -32,7 +39,7 @@ def make_train_mesh(n_data: int | None = None):
     visible devices; on the 1-device CPU container this is the host mesh.
     """
     n = n_data if n_data is not None else len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return _auto_mesh((n, 1), ("data", "model"))
 
 
 # ---------------------------------------------------------------------------

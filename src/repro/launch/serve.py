@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import get_config
+from repro.launch import compile_cache
 from repro.models import model as mdl
 from repro.sched.daemon import DaemonConfig, FleetSubstrate, PlacementDaemon
 from repro.sched.placement import JobSpec, fresh_fleet
@@ -86,6 +87,7 @@ def main(argv=None):
                     help="refresh cycles to run after the routing burst "
                          "(with --online)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     key = jax.random.PRNGKey(args.seed)

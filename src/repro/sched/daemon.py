@@ -821,17 +821,24 @@ class PlacementDaemon:
         ``n_real = 0``: every warmup row is a pad row, so a sequence
         policy's history carry is untouched by warming up.
         """
-        snap = self._sub.snapshot()
-        pods = self._sub.pack([self._dummy_pod()], self.config.batch_size)
-        jax.block_until_ready(
-            self._scorer(self._params, snap, pods, self._carry, 0))
+        jax.block_until_ready(self._scorer(*self._warm_args()))
 
     def scorer_cache_size(self) -> int:
         """Compilations of the batched scorer (1 == every batch, at every
         fill level, reused one executable)."""
         return self._scorer._cache_size()
 
+    def scorer_text(self) -> str:
+        """The batched scorer's compiled program text at the serving shapes
+        (e.g. to check which kernels the backend runs)."""
+        return self._scorer.lower(*self._warm_args()).compile().as_text()
+
     # -- internals ----------------------------------------------------------
+
+    def _warm_args(self):
+        """Scorer arguments at the serving shapes, all rows padding."""
+        pods = self._sub.pack([self._dummy_pod()], self.config.batch_size)
+        return self._params, self._sub.snapshot(), pods, self._carry, 0
 
     def _dummy_pod(self):
         if isinstance(self._sub, ClusterSubstrate):

@@ -12,7 +12,9 @@ axis the way the training engine scaled seed×env (``launch/mesh.py``):
   2. **Per-shard top-k, in-kernel** — each shard runs the fused scoring
      dispatch with the k8s filtering phase *and* a top-k reduction inside the
      kernel (``ops.sdqn_topk_afterstate`` / ``ops.sdqn_topk_delta``), so only
-     ``k`` (score, global-index) candidates per shard ever leave it.
+     ``k`` (score, global-index) candidates per shard ever leave it.  On a
+     device mesh this stage runs under ``shard_map``, each device scoring
+     its own shards.
      Non-fusable policy classes reduce their shard-local ``score_set``
      output with ``lax.top_k`` instead — same candidate contract.
   3. **Global merge** — one tiny top-k over the ``shards × k`` candidates.
@@ -128,6 +130,26 @@ def _shard_axes(tree):
                         for c in tree])
 
 
+def _over_shards(fn, layout: FleetLayout, tree, *shared):
+    """``fn(shard, *shared)`` for every shard of the sharded ``tree``.
+
+    On one device this is a ``vmap`` over the shard axis.  With a layout
+    mesh it runs under ``shard_map`` over ``data``, each device mapping its
+    own shards: the compiler cannot partition a Pallas kernel itself.
+    ``shared`` arguments (params, the pod, global scalars) are replicated.
+    """
+    mapped = jax.vmap(fn, in_axes=(_shard_axes(tree),) + (None,) * len(shared))
+    if layout.mesh is None:
+        return mapped(tree, *shared)
+    P = jax.sharding.PartitionSpec
+    specs = type(tree)(*[P("data") if getattr(c, "ndim", 0) >= 2 else P()
+                         for c in tree])
+    # check_vma=False: pallas_call's outputs carry no varying-axes type
+    return jax.shard_map(mapped, mesh=layout.mesh,
+                         in_specs=(specs,) + (P(),) * len(shared),
+                         out_specs=P("data"), check_vma=False)(tree, *shared)
+
+
 def _global_index(vals, local_idx, layout: FleetLayout):
     """(S, k) shard-local indices -> global node indices (−1 on dead slots)."""
     offs = (jnp.arange(layout.shards, dtype=jnp.int32)
@@ -168,7 +190,7 @@ def cluster_topk(params: dict, state: ClusterState, pod, cfg, layout: FleetLayou
         or (fused == "auto"
             and layout.shard_size >= schedulers.FUSED_SCORE_MIN_NODES))
 
-    def one_shard(sub):
+    def one_shard(sub, params, pod, embed, pull_cost):
         if heuristic:
             q = baselines.kube_scores(sub, pod, cfg)
         elif use_fused:
@@ -183,7 +205,8 @@ def cluster_topk(params: dict, state: ClusterState, pod, cfg, layout: FleetLayou
         ok = kenv.feasible(sub, pod, cfg)
         return jax.lax.top_k(jnp.where(ok, q, -jnp.inf), k)
 
-    vals, lidx = jax.vmap(one_shard, in_axes=(_shard_axes(st),))(st)
+    vals, lidx = _over_shards(one_shard, layout, st, params, pod, embed,
+                              pull_cost)
     return _merge(vals, _global_index(vals, lidx, layout))
 
 
@@ -208,13 +231,13 @@ def fleet_topk(params: dict, fleet: _pl.FleetState, job, layout: FleetLayout,
     ft = shard_fleet(fleet, layout)
     fused_path = not heuristic and (policy is None or policy.fused_kernel)
 
-    def feasible(sub):
+    def feasible(sub, delta):
         return ((sub.healthy > 0.5)
                 & (sub.cpu_pct + delta[0] <= ceilings[0])
                 & (sub.mem_pct + delta[1] <= ceilings[1])
                 & (sub.job_util_pct + delta[2] <= ceilings[2]))
 
-    def one_shard(sub):
+    def one_shard(sub, params, delta, embed):
         if fused_path:
             return ops.sdqn_topk_delta(_pl.fleet_cols(sub), delta, params,
                                        k=k, mode=_fleet_mode(fused),
@@ -230,9 +253,9 @@ def fleet_topk(params: dict, fleet: _pl.FleetState, job, layout: FleetLayout,
                      jnp.broadcast_to(embed, feats.shape[:-1] + embed.shape)],
                     axis=-1)
             q = policy.score_set(params, feats)
-        return jax.lax.top_k(jnp.where(feasible(sub), q, -jnp.inf), k)
+        return jax.lax.top_k(jnp.where(feasible(sub, delta), q, -jnp.inf), k)
 
-    vals, lidx = jax.vmap(one_shard)(ft)
+    vals, lidx = _over_shards(one_shard, layout, ft, params, delta, embed)
     return _merge(vals, _global_index(vals, lidx, layout))
 
 
@@ -306,25 +329,21 @@ def sharded_scores(fleet, pod, *, params: dict, cfg=None,
             raise ValueError("cfg (EnvConfig) is required to score a "
                              "ClusterState fleet")
         pull = kenv.pull_cost_now(fleet, cfg)
-        st = shard_cluster(fleet, layout)
-        q = jax.vmap(
-            lambda sub: schedulers.score_afterstates(
+        q = _over_shards(
+            lambda sub, params, pod, embed, pull: schedulers.score_afterstates(
                 params, sub, pod, cfg, score_fn=score_fn, fused=fused,
                 policy=policy, embed=embed, pull_cost=pull),
-            in_axes=(_shard_axes(st),))(st)
+            layout, shard_cluster(fleet, layout), params, pod, embed, pull)
         n = fleet.n_nodes
     elif isinstance(fleet, _pl.FleetState):
         from repro.sched import api as _api
 
-        ft = shard_fleet(fleet, layout)
-        q = jax.vmap(lambda sub: _api._score_raw(sub, pod, params=params,
-                                                 fused=fused, policy=policy,
-                                                 embed=embed))(ft)
+        q = _over_shards(
+            lambda sub, params, embed: _api._score_raw(
+                sub, pod, params=params, fused=fused, policy=policy,
+                embed=embed),
+            layout, shard_fleet(fleet, layout), params, embed)
         n = fleet.cpu_pct.shape[0]
     else:
         raise TypeError(f"unsupported fleet type: {type(fleet).__name__}")
-    if layout.mesh is not None:
-        q = jax.lax.with_sharding_constraint(
-            q, jax.sharding.NamedSharding(
-                layout.mesh, jax.sharding.PartitionSpec("data", None)))
     return q.reshape(-1)[:n]

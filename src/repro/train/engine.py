@@ -70,11 +70,16 @@ def _seed_train(keys, env_cfg: EnvConfig, rl: train_rl.RLConfig, mesh=None):
         return jax.vmap(lambda k: train_rl.train(k, env_cfg, rl))(keys)
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    if layout.env_shards == 1:
+        # pure seed sharding: whole replicas per device, each device training
+        # its own seeds (shard_map: the compiler cannot partition the Pallas
+        # scoring kernels inside a replica)
+        return jax.shard_map(
+            jax.vmap(lambda k: train_rl.train(k, env_cfg, rl)),
+            mesh=layout.mesh, in_specs=P("seed"), out_specs=P("seed"),
+            check_vma=False)(keys)
     keys = jax.lax.with_sharding_constraint(
         keys, NamedSharding(layout.mesh, P("seed")))
-    if layout.env_shards == 1:
-        # pure seed sharding: whole replicas per device, no inner constraints
-        return jax.vmap(lambda k: train_rl.train(k, env_cfg, rl))(keys)
     return jax.vmap(
         lambda k: train_rl.train(k, env_cfg, rl, mesh=layout.mesh),
         spmd_axis_name="seed",

@@ -146,6 +146,15 @@ class TestOneLaunch:
         assert d.metrics.device_launches == d.metrics.batches == 2
         assert d.scorer_cache_size() == 1
 
+    def test_scorer_text_leaves_the_serving_compile_alone(self, state,
+                                                          qparams):
+        d, _, _ = make_daemon(state, qparams, batch_size=4, max_wait_s=1e9)
+        d.warmup()
+        text = d.scorer_text()
+        assert text.startswith("HloModule")
+        # reading the program is not a second serving compilation
+        assert d.scorer_cache_size() == 1
+
     @pytest.mark.parametrize("policy", sorted(policy_mod.names()))
     def test_cluster_one_launch_one_compile_per_policy_class(
             self, state, policy):

@@ -6,7 +6,11 @@ assertions pin the module's core contract — the two-stage candidate merge
 selects exactly the node the flat masked argmax would, ties included.
 """
 import json
+import os
 import pathlib
+import subprocess
+import sys
+import textwrap
 
 import jax
 import jax.numpy as jnp
@@ -235,6 +239,81 @@ class TestDaemonSharded:
             nodes[label] = [dec.node for dec in d.decisions]
         assert len(nodes["sharded"]) == 6
         assert nodes["sharded"] == nodes["flat"]
+
+
+class TestDeviceMeshParity:
+    """A 4-device ``FleetLayout`` (CPU virtual devices, in a child process:
+    the device count is fixed when JAX starts) must select exactly what the
+    flat program selects.  On a mesh the per-shard stage runs under
+    ``shard_map``, one shard per device."""
+
+    _CHILD = textwrap.dedent("""
+        import json
+        import jax, jax.numpy as jnp
+        import numpy as np
+        from repro.core import dqn, env as kenv
+        from repro.core.types import fleet_cluster
+        from repro.launch.mesh import plan_fleet_layout
+        from repro.sched import api, placement
+        from repro.sched.daemon import (ClusterSubstrate, DaemonConfig,
+                                        PlacementDaemon)
+
+        N = 97
+        cfg = fleet_cluster(N)
+        state = kenv.reset(jax.random.PRNGKey(0), cfg)
+        pod = kenv.default_pod(cfg)
+        params = dqn.init_qnet(jax.random.PRNGKey(0))
+        mesh = jax.sharding.Mesh(np.array(jax.devices()), ("data",))
+        layout = plan_fleet_layout(N, mesh)
+        assert layout.shards == 4 and layout.mesh is not None, layout
+        checks = {}
+        flat = int(api.select(state, pod, params=params, cfg=cfg, shard=False))
+        for fused in (True, "interpret", False):
+            got = int(jax.jit(lambda s: api.select(
+                s, pod, params=params, cfg=cfg, shard=layout,
+                fused=fused))(state))
+            checks[f"select_{fused}"] = got == flat
+        checks["select_auto"] = int(api.select(
+            state, pod, params=params, cfg=cfg, shard="auto")) == flat
+        q = api.score(state, pod, params=params, cfg=cfg, shard=False)
+        qs = api.score(state, pod, params=params, cfg=cfg, shard=layout)
+        checks["scores"] = bool(np.allclose(qs, q, rtol=1e-5, atol=1e-5))
+        fleet = placement.fresh_fleet(N)
+        job = placement.JobSpec(cpu_pct_demand=4.0)
+        checks["fleet_select"] = int(api.select(
+            fleet, job, params=params, shard=layout)) == int(api.select(
+            fleet, job, params=params, shard=False))
+        nodes = {}
+        for label, lay in (("flat", None), ("mesh", layout)):
+            d = PlacementDaemon(ClusterSubstrate(state, cfg, layout=lay),
+                                params, DaemonConfig(batch_size=3,
+                                                     max_wait_s=1e9),
+                                clock=lambda: 0.0)
+            for _ in range(6):
+                d.submit(pod)
+            d.drain()
+            nodes[label] = [dec.node for dec in d.decisions]
+        checks["daemon"] = nodes["mesh"] == nodes["flat"]
+        print("PARITY" + json.dumps(checks))
+    """)
+
+    def test_four_device_layout_matches_flat(self):
+        import repro
+
+        env = dict(os.environ)
+        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
+                            " --xla_force_host_platform_device_count=4").strip()
+        env["JAX_PLATFORMS"] = "cpu"
+        src_dir = os.path.dirname(os.path.abspath(list(repro.__path__)[0]))
+        env["PYTHONPATH"] = os.pathsep.join(
+            [src_dir] + [p for p in (env.get("PYTHONPATH"),) if p])
+        out = subprocess.run([sys.executable, "-c", self._CHILD], env=env,
+                             capture_output=True, text=True, timeout=600)
+        assert out.returncode == 0, out.stderr[-2000:]
+        line = [ln for ln in out.stdout.splitlines()
+                if ln.startswith("PARITY")][-1]
+        checks = json.loads(line[len("PARITY"):])
+        assert len(checks) == 7 and all(checks.values()), checks
 
 
 class TestGatesManifest:

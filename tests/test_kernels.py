@@ -80,9 +80,9 @@ def test_sdqn_score_sweep(n):
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=2e-5, atol=2e-5)
 
 
-# N < block_n (64 -> padded to one block), N not a multiple of block_n
-# (padding path), and exact multiples
-@pytest.mark.parametrize("n", [1, 37, 64, 100, 1000])
+# N inside one (8, 128) tile (padding path), a whole tile, and N spanning
+# several grid steps with a ragged last block
+@pytest.mark.parametrize("n", [1, 37, 64, 100, 1000, 2500])
 @pytest.mark.parametrize("mode", ["interpret", "xla"])
 def test_sdqn_score_afterstate_sweep(n, mode):
     """In-kernel afterstate scoring == hypothetical_place + qvalues (<=1e-5).
@@ -106,12 +106,12 @@ def test_sdqn_score_afterstate_sweep(n, mode):
     want = dqn.qvalues(params, kenv.normalize_features(
         kenv.hypothetical_place(state, pod, cfg)))
     got = ops.sdqn_score_afterstate(state, pod, cfg, params, mode=mode,
-                                    block_n=64)
+                                    block_n=1024)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("n", [3, 64, 129])
+@pytest.mark.parametrize("n", [3, 64, 129, 2500])
 def test_sdqn_score_cols_sweep(n):
     """Fused column scorer (serving path) vs stack + normalize + qvalues."""
     from repro.core import env as kenv
@@ -123,9 +123,70 @@ def test_sdqn_score_cols_sweep(n):
     want = dqn.qvalues(params, (jnp.stack(cols, axis=-1) + deltas[None, :])
                        / kenv.FEATURE_SCALE)
     for mode in ("interpret", "xla"):
-        got = ops.sdqn_score_delta(cols, deltas, params, mode=mode, block_n=64)
+        got = ops.sdqn_score_delta(cols, deltas, params, mode=mode,
+                                   block_n=1024)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("n", [5, 1024, 3000])
+@pytest.mark.parametrize("kind", ["afterstate", "cols"])
+@pytest.mark.parametrize("case", ["random", "ties", "none_feasible"])
+def test_sdqn_topk_kernels_match_twins(kind, n, case):
+    """The in-kernel per-block top-k (interpret mode) emits exactly the XLA
+    twin's ``lax.top_k`` candidates.  ``ties`` makes every feasible node
+    score alike, so the first-index rule decides within and across the
+    (8, 128)-tile blocks; with no feasible node both list the lowest
+    indices at ``-inf``.  ``n = 3000`` spans three blocks."""
+    ties = case == "ties"
+    import dataclasses
+
+    from repro.core import env as kenv
+    from repro.core.types import fleet_cluster
+    from repro.sched import placement
+
+    params = dqn.init_qnet(jax.random.PRNGKey(9))
+    if kind == "afterstate":
+        cfg = dataclasses.replace(fleet_cluster(n), unhealthy_prob=0.2,
+                                  randomize_workload=True)
+        state = kenv.reset(jax.random.PRNGKey(10), cfg)
+        if ties:  # identical nodes, with the first two made infeasible
+            state = kenv.reset(jax.random.PRNGKey(10), fleet_cluster(n))
+            state = state._replace(
+                healthy=jnp.ones((n,), bool).at[:2].set(False),
+                uptime_hours=jnp.full((n,), 10.0),
+                num_pods=jnp.zeros((n,), state.num_pods.dtype),
+                cpu_requested=jnp.full((n,), 500.0),
+                base_cpu=jnp.full((n,), 100.0))
+        if case == "none_feasible":
+            state = state._replace(healthy=jnp.zeros((n,), bool))
+        pod = kenv.default_pod(cfg)
+
+        def run(mode):
+            return ops.sdqn_topk_afterstate(state, pod, cfg, params, k=4,
+                                            mode=mode)
+    else:
+        fleet = placement.fresh_fleet(n, jax.random.PRNGKey(11))
+        if ties:
+            fleet = fleet._replace(cpu_pct=jnp.full((n,), 50.0),
+                                   uptime_hours=jnp.full((n,), 10.0),
+                                   healthy=jnp.ones((n,)).at[:2].set(0.0))
+        if case == "none_feasible":
+            fleet = fleet._replace(healthy=jnp.zeros((n,)))
+        cols = placement.fleet_cols(fleet)
+        delta = placement.job_delta(placement.JobSpec(cpu_pct_demand=6.0))
+
+        def run(mode):
+            return ops.sdqn_topk_delta(cols, delta, params, k=4, mode=mode)
+
+    vals, idx = run("interpret")
+    tvals, tidx = run("xla")
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(tidx))
+    np.testing.assert_allclose(np.asarray(vals), np.asarray(tvals),
+                               rtol=1e-5, atol=1e-5)
+    if ties:  # the lowest feasible indices, in order
+        assert np.asarray(idx)[:min(4, n - 2)].tolist() == list(
+            range(2, 2 + min(4, n - 2)))
 
 
 class TestXlaPathsMatchOracles:

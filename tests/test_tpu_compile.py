@@ -1,0 +1,200 @@
+"""Ahead-of-time compiles of the main-path Pallas kernels for a TPU v5e.
+
+Nothing here runs on a chip: the TPU compiler compiles for a v5e that is
+described, not attached, and refuses what the chip's compiler would refuse
+(block shapes off the (8, 128) tiling, too much VMEM, unsupported Mosaic
+ops).  Each test asserts that the compiled program holds a Mosaic kernel
+(``tpu_custom_call``), so an XLA fallback cannot pass for the kernel.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and every test worker
+imports this file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import dqn, env as kenv, policy as pol
+from repro.core.types import fleet_cluster
+from repro.kernels import ops
+from repro.launch.mesh import plan_fleet_layout
+from repro.sched import api, placement
+from repro.sched.daemon import ClusterSubstrate, FleetSubstrate
+
+SIZES = (5000, 131072)       # upstream's large-cluster node limit; 128k fleet
+KERNELS = ("afterstate", "afterstate_topk", "cols", "cols_topk", "score")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without one; keep the cache out of these tests."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def pallas_default(monkeypatch):
+    """Make the library's backend-default dispatch pick the Pallas kernels,
+    as it does on a TPU (here ``default_backend()`` is the CPU)."""
+    monkeypatch.setattr(ops, "_default_mode", lambda: "pallas")
+
+
+def _shapes(tree, sharding, batch=()):
+    """Shapes (with an optional leading batch) of arrays, scalars or shapes,
+    placed on ``sharding``."""
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(batch + tuple(jnp.shape(x)),
+                                       jnp.result_type(x), sharding=sharding),
+        tree)
+
+
+def _assert_kernel(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _cluster(n):
+    cfg = fleet_cluster(n)
+    return cfg, jax.eval_shape(lambda: kenv.reset(jax.random.PRNGKey(0), cfg))
+
+
+PARAMS = dqn.init_qnet(jax.random.PRNGKey(0))
+
+
+@pytest.mark.parametrize("n", SIZES)
+@pytest.mark.parametrize("kernel", KERNELS)
+def test_sdqn_kernel_compiles(one_chip, kernel, n):
+    params = _shapes(PARAMS, one_chip)
+    if kernel.startswith("afterstate"):
+        cfg, state = _cluster(n)
+        pod = _shapes(kenv.default_pod(cfg), one_chip)
+        fn = (ops.sdqn_score_afterstate if kernel == "afterstate"
+              else ops.sdqn_topk_afterstate)
+        _assert_kernel(lambda s, p, w: fn(s, p, cfg, w, mode="pallas"),
+                       _shapes(state, one_chip), pod, params)
+    elif kernel.startswith("cols"):
+        fleet = _shapes(jax.eval_shape(lambda: placement.fresh_fleet(n)),
+                        one_chip)
+        delta = _shapes(jnp.zeros((6,)), one_chip)
+        fn = ops.sdqn_score_delta if kernel == "cols" else ops.sdqn_topk_delta
+        _assert_kernel(
+            lambda f, d, w: fn(placement.fleet_cols(f), d, w, mode="pallas"),
+            fleet, delta, params)
+    else:
+        feats = _shapes(jnp.zeros((n, 6)), one_chip)
+        _assert_kernel(lambda f, w: ops.sdqn_score(f, w, mode="pallas"),
+                       feats, params)
+
+
+@pytest.mark.parametrize("per_seed_params", [False, True])
+def test_afterstate_kernel_compiles_under_trainer_vmap(one_chip,
+                                                       per_seed_params):
+    """The training scan scores every env of the batch in one vmapped call
+    (``train``), and ``train_seeds`` vmaps again over per-seed params."""
+    n_envs = 8
+    cfg, state = _cluster(4096)
+    states = _shapes(state, one_chip, batch=(n_envs,))
+    pods = _shapes(kenv.default_pod(cfg), one_chip, batch=(n_envs,))
+    if per_seed_params:
+        params = _shapes(PARAMS, one_chip, batch=(n_envs,))
+        axes = (0, 0, 0)
+    else:
+        params = _shapes(PARAMS, one_chip)
+        axes = (0, 0, None)
+    _assert_kernel(
+        lambda s, p, w: jax.vmap(
+            lambda s1, p1, w1: ops.sdqn_score_afterstate(
+                s1, p1, cfg, w1, mode="pallas"), in_axes=axes)(s, p, w),
+        states, pods, params)
+
+
+@pytest.mark.parametrize("n", [64, 4096])
+def test_attention_policy_kernel_compiles(one_chip, n):
+    spec = pol.get("attention")
+    params = _shapes(spec.init(jax.random.PRNGKey(0)), one_chip)
+    feats = _shapes(jnp.zeros((n, spec.feature_dim)), one_chip)
+    _assert_kernel(lambda w, f: pol.attention_score_set(w, f, mode="pallas"),
+                   params, feats)
+
+
+def test_mamba_policy_kernel_compiles(one_chip):
+    params = _shapes(pol.get("mamba").init(jax.random.PRNGKey(0)), one_chip)
+    hist = _shapes(jnp.zeros((512, pol.ENCODER_IN)), one_chip)
+    _assert_kernel(lambda w, x: pol.mamba_encode_sequence(w, x, mode="pallas"),
+                   params, hist)
+
+
+def test_sharded_fleet_decision_compiles(one_chip, pallas_default):
+    """``api.select`` over 131,072 nodes in 8 forced shards: the per-shard
+    top-k kernel runs under ``vmap`` over the shard axis."""
+    n = 131072
+    cfg, state = _cluster(n)
+    layout = plan_fleet_layout(n, shards=8)
+    pod = kenv.default_pod(cfg)
+    _assert_kernel(
+        lambda s, w: api.select(s, pod, params=w, cfg=cfg, shard=layout,
+                                fused=True),
+        _shapes(state, one_chip), _shapes(PARAMS, one_chip))
+
+
+def test_sharded_fleet_decision_compiles_on_four_chips(topo, pallas_default):
+    """The same decision with a 4-device ``FleetLayout``: each chip runs the
+    top-k kernel on its own shard (``shard_map``; the compiler cannot
+    partition a Mosaic kernel)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    n = 131072
+    cfg, state = _cluster(n)
+    mesh = Mesh(np.array(topo.devices[:4]), ("data",))
+    layout = plan_fleet_layout(n, mesh)
+    assert layout.shards == 4 and layout.mesh is not None
+    replicated = NamedSharding(mesh, PartitionSpec())
+    pod = kenv.default_pod(cfg)
+    _assert_kernel(
+        lambda s, w: api.select(s, pod, params=w, cfg=cfg, shard=layout,
+                                fused=True),
+        _shapes(state, replicated), _shapes(PARAMS, replicated))
+
+
+@pytest.mark.parametrize("substrate", ["cluster", "fleet"])
+def test_daemon_scorer_compiles(one_chip, pallas_default, substrate):
+    """The daemon's one-launch batch scorer at 5,000 nodes, batch 32."""
+    n, batch = 5000, 32
+    if substrate == "cluster":
+        cfg = fleet_cluster(n)
+        sub = ClusterSubstrate(kenv.reset(jax.random.PRNGKey(0), cfg), cfg)
+        pods = sub.pack([kenv.default_pod(cfg)], batch)
+    else:
+        sub = FleetSubstrate(placement.fresh_fleet(n))
+        pods = sub.pack([placement.JobSpec()], batch)
+    snap = sub.snapshot()
+    scorer = sub.make_scorer("auto")
+    compiled = scorer.lower(
+        _shapes(PARAMS, one_chip), _shapes(snap, one_chip),
+        _shapes(pods, one_chip), (),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()

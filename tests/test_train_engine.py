@@ -236,11 +236,11 @@ class TestJointShardingParity:
     """Multi-device parity for the joint layouts, in a child process (the
     host platform can only be split into >1 device before jax initializes).
 
-    One child covers the three layout paths on a forced 4-device host:
-    joint (2, 2) at n_seeds=2, env-only (1, 4) at n_seeds=3 with the seed
-    axis indivisible, and the full fallback at an indivisible batch — each
-    pinned <= 1e-6 against the unsharded program with the identical
-    ``fold_in`` PRNG ladder."""
+    One child covers the layout paths on a forced 4-device host: joint
+    (2, 2) at n_seeds=2, env-only (1, 4) at n_seeds=3 with the seed axis
+    indivisible, seed-only (4, 1) at n_seeds=4, and the full fallback at an
+    indivisible batch — each pinned <= 1e-6 against the unsharded program
+    with the identical ``fold_in`` PRNG ladder."""
 
     _CHILD = textwrap.dedent("""
         import json
@@ -284,6 +284,10 @@ class TestJointShardingParity:
         assert (lay.seed_shards, lay.env_shards) == (1, 4), lay
         parity("env_only_1x4", rl4, 3)
 
+        lay = meshmod.plan_seed_env_layout(4, 4, mesh4)
+        assert (lay.seed_shards, lay.env_shards) == (4, 1), lay
+        parity("seed_only_4x1", rl4, 4)
+
         rl5 = train_rl.RLConfig(episodes=1, pods_per_episode=4, n_envs=5,
                                 batch_size=16, buffer_capacity=60)
         assert meshmod.plan_seed_env_layout(3, 5, mesh4) is None
@@ -311,7 +315,7 @@ class TestJointShardingParity:
         line = [ln for ln in out.stdout.splitlines()
                 if ln.startswith("PARITY")][-1]
         checks = json.loads(line[len("PARITY"):])
-        assert set(checks) == {"joint_2x2", "env_only_1x4",
+        assert set(checks) == {"joint_2x2", "env_only_1x4", "seed_only_4x1",
                                "fallback_unsharded"}
         assert all(v == "ok" for v in checks.values()), checks
 
