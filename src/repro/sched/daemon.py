@@ -46,7 +46,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import time
-from typing import Callable, List, NamedTuple, Optional, Sequence
+from typing import Callable, Dict, List, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -203,6 +203,16 @@ class DaemonMetrics:
     batches: int = 0
     device_launches: int = 0  # jitted scoring calls (degraded batches skip)
     fallback_batches: int = 0  # batches served by the kube heuristic
+    commit_calls: int = 0   # requests through the commit loop (per attempt)
+    walk_steps: int = 0     # next-best candidates re-validated after the first
+    taken: int = 0          # requests taken into a batch (per attempt)
+    queue_wait_s: float = 0.0  # summed over taken: take - (re)enqueue time
+    upload_bytes: int = 0   # device bytes of published snapshots + pod batches
+    readback_bytes: int = 0  # bytes of scorer outputs copied to the host
+    # per batch-loop stage (``sched.batch``, ``sched.snapshot``, ...; see
+    # ``_Span``): summed wall seconds and the number of times it ran
+    stage_s: Dict[str, float] = dataclasses.field(default_factory=dict)
+    stage_n: Dict[str, int] = dataclasses.field(default_factory=dict)
     # decision latency of SERVED requests (bound or dropped) — the p50/p99
     # the placement_serve gate measures.  Shed requests live in shed_wait_s:
     # mixing the two meant that under backpressure the p99 gate measured
@@ -212,32 +222,51 @@ class DaemonMetrics:
     shed_wait_s: LatencyReservoir = dataclasses.field(
         default_factory=LatencyReservoir)
 
-    @property
-    def latencies_s(self) -> LatencyReservoir:
-        """Deprecated alias of ``bind_latencies_s`` (the pre-split field
-        mixed shed wait times into the decision-latency stream)."""
-        import warnings
-
-        warnings.warn("DaemonMetrics.latencies_s is deprecated: use "
-                      "bind_latencies_s (served decisions) or shed_wait_s "
-                      "(backpressure evictions)", DeprecationWarning,
-                      stacklevel=2)
-        return self.bind_latencies_s
-
 
 # the public name the ops surface documents; the dataclass predates it
 DaemonStats = DaemonMetrics
 
 
 class _Request:
-    __slots__ = ("req_id", "pod", "t_submit", "attempts", "not_before")
+    __slots__ = ("req_id", "pod", "t_submit", "t_enqueued", "attempts",
+                 "not_before")
 
     def __init__(self, req_id, pod, t_submit):
         self.req_id = req_id
         self.pod = pod
         self.t_submit = t_submit
+        self.t_enqueued = t_submit   # last (re)entry into the queue
         self.attempts = 0
         self.not_before = t_submit   # conflict-backoff hold (poll honors it)
+
+
+class _Span:
+    """One stage of the batch loop, as a profiler span and a counter.
+
+    The span is a ``jax.profiler.TraceAnnotation``: while a profiler session
+    runs it lands on the profiler's host clock, the clock of the device
+    trace; otherwise it is a no-op.  Its ``perf_counter`` duration is always
+    added to ``metrics.stage_s[name]`` and 1 to ``metrics.stage_n[name]`` —
+    the operator's per-stage time, on or off the profiler.  ``meta`` (ints)
+    is attached to the trace event.
+    """
+
+    __slots__ = ("_metrics", "_name", "_ann", "_t0")
+
+    def __init__(self, metrics: DaemonMetrics, name: str, **meta):
+        self._metrics, self._name = metrics, name
+        self._ann = jax.profiler.TraceAnnotation(name, **meta)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        m, name = self._metrics, self._name
+        m.stage_s[name] = m.stage_s.get(name, 0.0) + dt
+        m.stage_n[name] = m.stage_n.get(name, 0) + 1
 
 
 # ---------------------------------------------------------------------------
@@ -682,6 +711,11 @@ class PlacementDaemon:
     jitted launch, and commits binds with bind-time re-validation.
     ``flush``/``drain`` force remaining work through.  ``clock`` is
     injectable for deterministic tests (defaults to ``time.monotonic``).
+
+    Each stage of a batch (``sched.snapshot``, ``sched.pack``,
+    ``sched.launch``, ``sched.readback``, ``sched.commit``, inside
+    ``sched.batch``) is a profiler span and a per-stage counter in
+    ``metrics.stage_s`` / ``stage_n`` (``_Span``).
     """
 
     def __init__(self, substrate, params: dict,
@@ -717,6 +751,7 @@ class PlacementDaemon:
         # > 0: this many upcoming batches skip the Q-net launch and serve
         # from the kube heuristic (set on a deadline breach / NaN scores)
         self._degraded = 0
+        self._upload_nbytes = None   # snapshot + packed batch, device bytes
         self.metrics = DaemonMetrics()
         self.decisions: List[Decision] = []
 
@@ -859,39 +894,50 @@ class PlacementDaemon:
                 held.append(req)
         for req in reversed(held):
             self._pending.appendleft(req)
+        self.metrics.taken += len(take)
+        self.metrics.queue_wait_s += sum(now - r.t_enqueued for r in take)
         return take
 
     def _process_batch(self, now: float, force: bool = False) -> int:
         reqs = self._take_batch(now, force)
         if not reqs:
             return 0
+        m = self.metrics
+        # one span a stage a batch (never a request); the batch's id and
+        # size ride on the enclosing span
+        with _Span(m, "sched.batch", batch=m.batches, n=len(reqs)):
+            return self._score_and_commit(reqs, now)
+
+    def _score_and_commit(self, reqs: List[_Request], now: float) -> int:
+        m = self.metrics
         scores = ok = cand_idx = None
         degraded = self.config.heuristic_only or self._degraded > 0
         if not degraded:
             # publish the admission buffer as the read (scoring) snapshot;
             # the live buffer keeps taking writes from here on
-            snap = self._sub.snapshot()
-            pods = self._sub.pack([r.pod for r in reqs],
-                                  self.config.batch_size)
+            with _Span(m, "sched.snapshot"):
+                snap = self._sub.snapshot()
+            with _Span(m, "sched.pack"):
+                pods = self._sub.pack([r.pod for r in reqs],
+                                      self.config.batch_size)
+            if self._upload_nbytes is None:
+                # static shapes (one compilation), so the same bytes every
+                # batch; ``nbytes`` of a device array costs microseconds
+                self._upload_nbytes = sum(
+                    x.nbytes for x in jax.tree.leaves((snap, pods)))
+            m.upload_bytes += self._upload_nbytes
             t0 = self._timer()
-            q, okq, carry2 = self._scorer(
-                self._params, snap, pods, self._carry, len(reqs))  # 1 launch
-            q = np.asarray(q)
-            elapsed = self._timer() - t0
-            self.metrics.device_launches += 1
+            with _Span(m, "sched.launch"):
+                q, okq, carry2 = self._scorer(
+                    self._params, snap, pods, self._carry,
+                    len(reqs))  # 1 launch
+            with _Span(m, "sched.readback"):
+                q, okq = np.asarray(q), np.asarray(okq)
+                elapsed = self._timer() - t0
+                bad = self._diverged(q[:len(reqs)])
+            m.device_launches += 1
+            m.readback_bytes += q.nbytes + okq.nbytes
             deadline = self.config.score_deadline_s
-            real = q[:len(reqs)]
-            if self._cand_mode:
-                # candidate lists legitimately carry -inf (infeasible /
-                # exhausted slots) — divergence means NaN, or a FINITE
-                # candidate outside the limit
-                finite = np.isfinite(real)
-                bad = bool(np.isnan(real).any()
-                           or (np.where(finite, np.abs(real), 0.0)
-                               > _DIVERGENCE_LIMIT).any())
-            else:
-                bad = (not np.all(np.isfinite(real))
-                       or float(np.max(np.abs(real))) > _DIVERGENCE_LIMIT)
             if bad or (deadline is not None and elapsed > deadline):
                 # degrade: discard the launch (scores AND its history-carry
                 # advance) and serve this + the next degrade_batches batches
@@ -901,13 +947,13 @@ class PlacementDaemon:
             else:
                 self._carry = carry2
                 if self._cand_mode:
-                    scores, cand_idx = q, np.asarray(okq)
+                    scores, cand_idx = q, okq
                 else:
-                    scores, ok = q, np.asarray(okq)
+                    scores, ok = q, okq
         if degraded:
             if not self.config.heuristic_only and self._degraded > 0:
                 self._degraded -= 1
-            self.metrics.fallback_batches += 1
+            m.fallback_batches += 1
             scores, ok = self._sub.heuristic_batch([r.pod for r in reqs])
             if self._cand_mode:
                 # degraded mode is host-side numpy by design (no device
@@ -917,15 +963,30 @@ class PlacementDaemon:
                 masked = np.where(ok, scores, -np.inf)
                 cand_idx = np.argsort(-masked, axis=1, kind="stable")
                 scores = np.take_along_axis(masked, cand_idx, axis=1)
-        self.metrics.batches += 1
+        m.batches += 1
+        m.commit_calls += len(reqs)
         decided = 0
-        for i, req in enumerate(reqs):
-            if self._cand_mode:
-                decided += self._commit_candidates(req, scores[i],
-                                                   cand_idx[i], now)
-            else:
-                decided += self._commit(req, scores[i], ok[i], now)
+        with _Span(m, "sched.commit"):
+            for i, req in enumerate(reqs):
+                if self._cand_mode:
+                    decided += self._commit_candidates(req, scores[i],
+                                                       cand_idx[i], now)
+                else:
+                    decided += self._commit(req, scores[i], ok[i], now)
         return decided
+
+    def _diverged(self, real: np.ndarray) -> bool:
+        """NaN or out-of-limit scores in the batch's real rows."""
+        if self._cand_mode:
+            # candidate lists legitimately carry -inf (infeasible /
+            # exhausted slots) — divergence means NaN, or a FINITE
+            # candidate outside the limit
+            finite = np.isfinite(real)
+            return bool(np.isnan(real).any()
+                        or (np.where(finite, np.abs(real), 0.0)
+                            > _DIVERGENCE_LIMIT).any())
+        return (not np.all(np.isfinite(real))
+                or float(np.max(np.abs(real))) > _DIVERGENCE_LIMIT)
 
     def _decide(self, req: _Request, node: int) -> None:
         lat = max(self._clock() - req.t_submit, 0.0)
@@ -961,13 +1022,17 @@ class PlacementDaemon:
         # an earlier bind (or external churn) before this request's turn
         self.metrics.conflicts += 1
         if self.config.conflict_policy == "next-best":
+            walked = 0
             for cand in np.argsort(-masked)[1:]:
                 if not np.isfinite(masked[cand]):
                     break
+                walked += 1
                 if self._sub.feasible_one(int(cand), req.pod):
+                    self.metrics.walk_steps += walked
                     self._sub.bind(int(cand), req.pod)
                     self._decide(req, int(cand))
                     return 1
+            self.metrics.walk_steps += walked
         return self._requeue_or_drop(req, now)
 
     def _commit_candidates(self, req: _Request, vals: np.ndarray,
@@ -991,13 +1056,17 @@ class PlacementDaemon:
             return 1
         self.metrics.conflicts += 1
         if self.config.conflict_policy == "next-best":
+            walked = 0
             for v, cand in zip(vals[1:], idx[1:]):
                 if not np.isfinite(v):
                     break
+                walked += 1
                 if self._sub.feasible_one(int(cand), req.pod):
+                    self.metrics.walk_steps += walked
                     self._sub.bind(int(cand), req.pod)
                     self._decide(req, int(cand))
                     return 1
+            self.metrics.walk_steps += walked
         return self._requeue_or_drop(req, now)
 
     def _requeue_or_drop(self, req: _Request, now: float) -> int:
@@ -1010,6 +1079,7 @@ class PlacementDaemon:
         if self.config.backoff_base_s > 0:
             req.not_before = now + (self.config.backoff_base_s
                                     * 2.0 ** (req.attempts - 1))
+        req.t_enqueued = self._clock()
         self._pending.appendleft(req)
         return 0
 

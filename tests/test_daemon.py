@@ -10,6 +10,8 @@ the unified ``repro.sched.api`` dispatch, the arrival-trace adapter, the
 ``EpisodeResult`` shim, and ``serve.load_qnet`` checkpoint loading.
 """
 import dataclasses
+import glob
+import os
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +24,7 @@ from repro.core.types import (
     EpisodeResult,
     paper_cluster,
 )
+from repro.launch.mesh import plan_fleet_layout
 from repro.scenarios import arrival_trace, trace_from_table
 from repro.sched import api, placement
 from repro.sched.daemon import (
@@ -687,7 +690,177 @@ class TestLatencyReservoir:
         from repro.sched.daemon import LatencyReservoir
 
         d, _, _ = make_daemon(state, qparams)
-        assert isinstance(d.metrics.latencies_s, LatencyReservoir)
+        assert isinstance(d.metrics.bind_latencies_s, LatencyReservoir)
+
+
+# ---------------------------------------------------------------------------
+# batch-loop spans and counters
+# ---------------------------------------------------------------------------
+
+STAGES = ("sched.snapshot", "sched.pack", "sched.launch", "sched.readback",
+          "sched.commit")
+LAYOUTS = {"flat": None, "sharded": plan_fleet_layout(CFG.n_nodes, shards=2)}
+
+
+def _sched_events(log_dir):
+    """``(name, start_ns, end_ns, stats)`` of every ``sched.*`` host event in
+    the one ``.xplane.pb`` the profiler wrote under ``log_dir``."""
+    paths = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert len(paths) == 1
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(paths[0]).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                out.extend((e.name, e.start_ns, e.start_ns + e.duration_ns,
+                            dict(e.stats)) for e in line.events
+                           if e.name.startswith("sched."))
+    return out
+
+
+def _walk_race(qparams, layout):
+    """Four requests in one batch, all preferring node 0 < 1 < 2 < 3 by
+    snapshot score.  Nodes 0-2 fit one pod each and node 3 none, so request
+    k binds node k after re-validating k candidates past the first, and the
+    last walks nodes 1 and 2, stops at node 3 (infeasible in the snapshot)
+    and is re-queued, to be dropped on its next batch."""
+    state = kenv.reset(jax.random.PRNGKey(2), CFG)
+    sub = ClusterSubstrate(state, CFG, score_fn=lambda p, f: -f[:, 0],
+                           layout=layout, topk=2)
+    lv = sub.live
+    lv.healthy[:] = True
+    lv.image_cached[:] = True
+    lv.base_cpu[:] = (100.0, 200.0, 300.0, 400.0)
+    for col in (lv.pods_cpu, lv.startup_cpu, lv.cpu_requested,
+                lv.mem_requested, lv.mem_used):
+        col[:] = 0
+    lv.max_pods[:] = lv.num_pods + np.array([1, 1, 1, 0])
+    d = PlacementDaemon(sub, qparams,
+                        DaemonConfig(batch_size=4, max_wait_s=1e9,
+                                     conflict_policy="next-best"),
+                        clock=FakeClock())
+    pod = kenv.default_pod(CFG)
+    for _ in range(4):
+        d.submit(pod)
+    return d
+
+
+class TestBatchLoopSpans:
+    @pytest.mark.parametrize("layout", sorted(LAYOUTS))
+    def test_stage_spans_nest_in_batch_on_the_profiler(self, state, qparams,
+                                                       tmp_path, layout):
+        sub = ClusterSubstrate(state, CFG, layout=LAYOUTS[layout], topk=2)
+        d = PlacementDaemon(sub, qparams,
+                            DaemonConfig(batch_size=4, max_wait_s=1e9),
+                            clock=FakeClock())
+        d.warmup()
+        pod = kenv.default_pod(CFG)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            for fill in (4, 2):
+                for _ in range(fill):
+                    d.submit(pod)
+                d.flush()
+        finally:
+            jax.profiler.stop_trace()
+        events = _sched_events(str(tmp_path))
+        batches = sorted((e for e in events if e[0] == "sched.batch"),
+                         key=lambda e: e[1])
+        # one span a stage a batch: never one a request
+        assert [(b[3]["batch"], b[3]["n"]) for b in batches] == [(0, 4),
+                                                                 (1, 2)]
+        for name in STAGES:
+            spans = [e for e in events if e[0] == name]
+            assert len(spans) == 2, name
+            for (_, s, e, _), b in zip(sorted(spans, key=lambda e: e[1]),
+                                       batches):
+                assert b[1] <= s and e <= b[2], name
+        # the always-on counters saw the same spans
+        assert d.metrics.stage_n == {n: 2 for n in ("sched.batch",) + STAGES}
+        assert all(v > 0 for v in d.metrics.stage_s.values())
+        assert d.metrics.stage_s["sched.batch"] >= sum(
+            d.metrics.stage_s[n] for n in STAGES)
+
+    @pytest.mark.parametrize("layout", sorted(LAYOUTS))
+    def test_walk_and_commit_counters_exact(self, qparams, layout):
+        d = _walk_race(qparams, LAYOUTS[layout])
+        assert d.flush() == 3
+        assert [dec.node for dec in d.decisions] == [0, 1, 2]
+        m = d.metrics
+        assert (m.conflicts, m.requeued) == (3, 1)
+        assert m.walk_steps == 0 + 1 + 2 + 2
+        assert (m.commit_calls, m.taken) == (4, 4)
+        assert d.flush() == 1                 # nothing left: dropped
+        assert d.decisions[-1].node == NO_PLACEMENT
+        assert (m.walk_steps, m.commit_calls, m.taken) == (5, 5, 5)
+
+    def test_queue_wait_exact_with_requeue(self, qparams):
+        d = _two_node_race(qparams)           # both submitted at t = 0
+        clock = d._clock
+        clock.t = 1.0
+        assert d.poll(clock.t) == 1           # the loser re-queued at 1.0
+        assert (d.metrics.taken, d.metrics.queue_wait_s) == (2, 2.0)
+        clock.t = 3.5
+        assert d.flush() == 1
+        assert (d.metrics.taken, d.metrics.queue_wait_s) == (3, 4.5)
+        assert d.metrics.commit_calls == 3
+
+    @pytest.mark.parametrize("layout", sorted(LAYOUTS))
+    def test_upload_and_readback_bytes(self, state, qparams, layout):
+        sub = ClusterSubstrate(state, CFG, layout=LAYOUTS[layout], topk=2)
+        d = PlacementDaemon(sub, qparams,
+                            DaemonConfig(batch_size=4, max_wait_s=1e9),
+                            clock=FakeClock())
+        pod = kenv.default_pod(CFG)
+        for _ in range(6):
+            d.submit(pod)
+        d.drain()
+        assert d.metrics.batches == 2
+        snap, pods = sub.snapshot(), sub.pack([pod], 4)
+        up = sum(x.nbytes for x in jax.tree.leaves((snap, pods)))
+        out0, out1, _ = d._scorer(qparams, snap, pods, (), 1)
+        back = np.asarray(out0).nbytes + np.asarray(out1).nbytes
+        if layout == "flat":                  # (B, N) float32 scores + bools
+            assert back == 4 * CFG.n_nodes * (4 + 1)
+        else:                                 # (B, C) float32 + int32 lists
+            assert back == 4 * 2 * 2 * (4 + 4)
+        assert d.metrics.upload_bytes == 2 * up
+        assert d.metrics.readback_bytes == 2 * back
+
+
+def _scorer_variants():
+    """(name, substrate factory) for every ``make_scorer`` branch."""
+    fleet_layout = plan_fleet_layout(8, shards=2)
+    out = []
+    for policy in [None] + sorted(policy_mod.names()):
+        for layout in sorted(LAYOUTS):
+            out.append((f"cluster-{layout}-{policy}",
+                        lambda p=policy, lay=LAYOUTS[layout]: ClusterSubstrate(
+                            kenv.reset(jax.random.PRNGKey(1), CFG), CFG,
+                            policy=p and policy_mod.get(p), layout=lay,
+                            topk=2)))
+            out.append((f"fleet-{layout}-{policy}",
+                        lambda p=policy, lay=layout: FleetSubstrate(
+                            placement.fresh_fleet(8),
+                            policy=p and policy_mod.get(p),
+                            layout=fleet_layout if lay == "sharded" else None,
+                            topk=2)))
+    return out
+
+
+@pytest.mark.parametrize("make_sub", [f for _, f in _scorer_variants()],
+                         ids=[n for n, _ in _scorer_variants()])
+def test_every_scorer_is_the_jit_score_module(make_sub):
+    """The device trace names the scorer's program ``jit_score(...)``; the
+    benchmark's roofline reader finds the scorer by that name, so every
+    ``make_scorer`` branch must keep it."""
+    sub = make_sub()
+    spec = sub.policy
+    params = (spec.init(jax.random.PRNGKey(0)) if spec is not None
+              else dqn.init_qnet(jax.random.PRNGKey(0)))
+    d = PlacementDaemon(sub, params, DaemonConfig(batch_size=2))
+    text = d._scorer.lower(*d._warm_args()).as_text()
+    assert text.startswith("module @jit_score ")
 
 
 class TestServeCheckpointLoading:

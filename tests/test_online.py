@@ -28,7 +28,6 @@ from repro.sched import api, topsis
 from repro.sched.daemon import (
     ClusterSubstrate,
     DaemonConfig,
-    DaemonMetrics,
     LatencyReservoir,
     PlacementDaemon,
 )
@@ -472,13 +471,6 @@ class TestLatencySplit:
         assert m.shed == 1 and m.bound == 2
         assert len(m.shed_wait_s) == 1 and len(m.bind_latencies_s) == 2
         assert m.shed_wait_s.percentile(50) == pytest.approx(0.5)
-
-    def test_latencies_s_deprecation_shim(self):
-        m = DaemonMetrics()
-        m.bind_latencies_s.append(0.25)
-        with pytest.warns(DeprecationWarning, match="bind_latencies_s"):
-            legacy = m.latencies_s
-        assert legacy is m.bind_latencies_s
 
     def test_empty_reservoir_percentile_is_nan(self):
         r = LatencyReservoir()
