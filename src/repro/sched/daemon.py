@@ -14,7 +14,10 @@ placement decisions under load.  This daemon is that serving loop:
     binds) writes the *live* buffer — a mutable host-side (numpy) mirror —
     while scoring reads a frozen device *snapshot* published at batch cut.
     Request intake is a queue append plus numpy writes and never blocks on a
-    device launch; the snapshot publish is an O(columns) transfer.
+    device launch.  ``ClusterSubstrate`` keeps the snapshot on the device
+    between batches and publishes only the rows that changed since the last
+    publish: one packed transfer and one jitted scatter, whatever the fleet
+    size (``_DeviceSnapshot``).
   * **Optimistic concurrency.**  Scores are computed against the snapshot,
     but by bind time the live buffer may have moved (earlier binds in the
     same batch, external churn applied through ``substrate.live``).  Every
@@ -45,6 +48,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import time
 from typing import Callable, Dict, List, NamedTuple, Optional, Sequence
 
@@ -207,7 +211,10 @@ class DaemonMetrics:
     walk_steps: int = 0     # next-best candidates re-validated after the first
     taken: int = 0          # requests taken into a batch (per attempt)
     queue_wait_s: float = 0.0  # summed over taken: take - (re)enqueue time
-    upload_bytes: int = 0   # device bytes of published snapshots + pod batches
+    upload_bytes: int = 0   # host-to-device bytes: snapshot publishes + pods
+    full_publishes: int = 0   # snapshots published whole
+    delta_publishes: int = 0  # snapshots published as their changed rows
+    publish_rows: int = 0     # rows the delta publishes sent
     readback_bytes: int = 0  # bytes of scorer outputs copied to the host
     # per batch-loop stage (``sched.batch``, ``sched.snapshot``, ...; see
     # ``_Span``): summed wall seconds and the number of times it ran
@@ -273,13 +280,183 @@ class _Span:
 # substrates: live-buffer mirror + batched snapshot scorer
 # ---------------------------------------------------------------------------
 
+# rows a delta publish carries (capped at N): a batch's binds plus the writes
+# made between batches (retirements, churn) fit; a larger change falls back
+# to publishing the whole state
+DELTA_ROWS = 256
+
+
+class _Publish(NamedTuple):
+    """What one snapshot publish sent to the device."""
+
+    full: bool     # the whole state; else only the rows changed since the last
+    rows: int      # rows a delta publish sent (0 for a full one)
+    nbytes: int    # host-to-device bytes
+
+
+def _bit_view(a: np.ndarray) -> np.ndarray:
+    """``a`` as unsigned integers of its width, so ``!=`` compares bits: a
+    NaN equals itself and ``-0.0`` differs from ``0.0``, as on the device."""
+    return a.view(f"u{a.itemsize}")
+
+
+@functools.partial(jax.jit, static_argnums=(2,))
+def _scatter_rows(cols, buf, layout):
+    """The node columns ``cols`` with the packed rows of ``buf`` written in.
+
+    ``buf`` is (C, 1 + columns) int32: column 0 the row indices, column
+    ``1 + j`` the values of ``cols[j]`` as 32-bit words (bools as 0/1).
+    Padding rows carry an out-of-range index, which ``mode="drop"`` skips.
+    In a sharded layout row ``r`` lands at ``(r // shard_size, r %
+    shard_size)``.  Nothing is donated: the previous snapshot stays valid
+    for whoever still holds it.
+    """
+    idx = buf[:, 0]
+    at = (idx,) if layout is None else (idx // layout.shard_size,
+                                        idx % layout.shard_size)
+    out = []
+    for col, w in zip(cols, buf[:, 1:].T):
+        vals = (w != 0 if col.dtype == jnp.bool_
+                else jax.lax.bitcast_convert_type(w, col.dtype))
+        col = col.at[at].set(vals, mode="drop")
+        if layout is not None and layout.mesh is not None:
+            col = jax.lax.with_sharding_constraint(
+                col, jax.sharding.NamedSharding(
+                    layout.mesh, jax.sharding.PartitionSpec("data", None)))
+        out.append(col)
+    return out
+
+
+class _DeviceSnapshot:
+    """The scoring snapshot, kept on the device between publishes.
+
+    ``dev`` is the device state the scorer reads: flat ``(N,)`` columns, or
+    ``shard_cluster``'s padded ``(shards, shard_size)`` columns with a
+    ``layout``.  ``_mirror`` is a host copy of what ``dev`` was made from.
+    ``publish(live)`` compares every column of ``live`` with the mirror
+    bitwise and sends only the rows that differ, packed with their indices
+    into one (C, 1 + columns) buffer: one transfer, one jitted scatter
+    (``_scatter_rows``).  It compares rather than tracks writes because
+    callers may write ``substrate.live`` directly.
+
+    It publishes the whole state instead (``jnp.asarray`` of every column,
+    then ``shard_cluster``) on the first publish, when ``live`` was replaced,
+    when a shape, a dtype or a scalar field (``time_s``) changed, when more
+    than C rows changed, and always when a column is not 1-D of N rows with
+    a 32-bit or bool device dtype.
+    """
+
+    def __init__(self, layout=None):
+        self.layout = layout
+        self.dev = None
+        self._live = None      # the live tree the mirror copies
+        self._mirror: list = []
+        self._cap = 0          # C: rows a delta carries; 0 = whole only
+        self._pad = 0          # row index past every layout's last row
+        self._dtypes: list = []  # each node column's device dtype
+
+    def publish(self, live) -> _Publish:
+        if live is self._live and self._cap:
+            pub = self._delta(jax.tree.leaves(live))
+            if pub is not None:
+                return pub
+        return self._full(live)
+
+    def warm(self) -> None:
+        """Compile the delta publish's scatter (one program: C is fixed)."""
+        if self._cap:
+            jax.block_until_ready(self._scatter(self._buffer()))
+
+    def _scatter(self, buf):
+        """``dev`` with ``buf``'s rows written into its node columns; the
+        scalar fields stay the arrays they are, on the devices they are."""
+        leaves, tree = jax.tree.flatten(self.dev)
+        cols = iter(_scatter_rows([x for x in leaves if x.ndim], buf,
+                                  self.layout))
+        return tree.unflatten([next(cols) if x.ndim else x for x in leaves])
+
+    def _buffer(self) -> np.ndarray:
+        buf = np.zeros((self._cap, 1 + len(self._dtypes)), np.int32)
+        buf[:, 0] = self._pad
+        return buf
+
+    def _full(self, live) -> _Publish:
+        # from a fresh host copy: on the CPU backend a device array may
+        # alias the numpy buffer it was made from, and ``live`` moves on
+        snap = jax.tree.map(lambda x: jnp.asarray(np.array(x)), live)
+        nbytes = sum(x.nbytes for x in jax.tree.leaves(snap))
+        dtypes = [x.dtype for x in jax.tree.leaves(snap)]
+        if self.layout is not None:
+            from repro.sched import shard as _shard
+
+            snap = _shard.shard_cluster(snap, self.layout)
+        self.dev = snap
+        leaves = [np.asarray(x) for x in jax.tree.leaves(live)]
+        if live is self._live and all(
+                m.shape == a.shape and m.dtype == a.dtype
+                for m, a in zip(self._mirror, leaves)):
+            for m, a in zip(self._mirror, leaves):
+                np.copyto(m, a)
+        else:
+            self._live = live
+            self._mirror = [np.array(a) for a in leaves]
+            cols = [a for a in leaves if a.ndim]
+            n = cols[0].shape[0] if cols else 0
+            ok = n > 0 and all(
+                a.ndim == 0 or (a.ndim == 1 and a.shape[0] == n
+                                and (dt == np.bool_ or dt.itemsize == 4))
+                for a, dt in zip(leaves, dtypes))
+            self._cap = min(DELTA_ROWS, n) if ok else 0
+            self._pad = n if self.layout is None else self.layout.padded
+            self._dtypes = [dt for a, dt in zip(leaves, dtypes) if a.ndim]
+        return _Publish(True, 0, nbytes)
+
+    def _delta(self, leaves) -> Optional[_Publish]:
+        """The delta publish, or None where it must publish whole."""
+        changed = None
+        for a, m in zip(leaves, self._mirror):
+            a = np.asarray(a)
+            if a.shape != m.shape or a.dtype != m.dtype:
+                return None
+            if a.ndim == 0:
+                if a.tobytes() != m.tobytes():
+                    return None
+            elif changed is None:
+                changed = _bit_view(a) != _bit_view(m)
+            else:
+                changed |= _bit_view(a) != _bit_view(m)
+        rows = np.flatnonzero(changed)
+        k = rows.size
+        if k > self._cap:
+            return None
+        if k == 0:
+            return _Publish(False, 0, 0)
+        buf = self._buffer()
+        buf[:k, 0] = rows
+        cols = (am for am in zip(leaves, self._mirror) if am[1].ndim)
+        for j, ((a, m), dt) in enumerate(zip(cols, self._dtypes), 1):
+            vals = np.asarray(a)[rows]
+            m[rows] = vals
+            vals = vals.astype(dt, copy=False)
+            buf[:k, j] = vals if dt == np.bool_ else vals.view(np.int32)
+        self.dev = self._scatter(buf)
+        return _Publish(False, k, buf.nbytes)
+
 
 class ClusterSubstrate:
     """The paper's pod->node cluster as a daemon substrate.
 
     ``live`` is a ``ClusterState`` of *mutable numpy* arrays — the admission
-    buffer.  ``snapshot`` publishes it as device arrays for the scoring
-    launch.  ``bind``/``feasible_one`` mirror ``env.place``/``env.feasible``
+    buffer, and the single source of truth; callers may write it directly.
+    ``snapshot`` publishes it as device arrays for the scoring launch.  The
+    device copy stays resident between publishes, and a publish sends only
+    the rows that differ from what the device holds: at most ``DELTA_ROWS``
+    rows with their indices, as one (C, 1 + columns) int32 buffer applied
+    by one jitted scatter.  It sends the whole state on the first publish,
+    after ``live`` is replaced, when ``time_s`` or a shape or dtype changed,
+    or when more than C rows changed (``_DeviceSnapshot``).
+    ``last_publish`` says what the latest publish sent.
+    ``bind``/``feasible_one`` mirror ``env.place``/``env.feasible``
     restricted to the touched row (parity pinned in tests/test_daemon.py).
     """
 
@@ -300,14 +477,19 @@ class ClusterSubstrate:
         self.layout = layout
         self.topk = topk
         self.live = jax.tree.map(lambda x: np.array(x), state)
+        self._snap = _DeviceSnapshot(layout)
+        self.last_publish: Optional[_Publish] = None
 
     def snapshot(self) -> ClusterState:
-        snap = jax.tree.map(jnp.asarray, self.live)
-        if self.layout is not None:
-            from repro.sched import shard as _shard
+        self.last_publish = self._snap.publish(self.live)
+        return self._snap.dev
 
-            snap = _shard.shard_cluster(snap, self.layout)
-        return snap
+    def warm_publish(self) -> None:
+        """Compile the delta publish outside any timing window (publishing
+        first if nothing has been published yet)."""
+        if self._snap.dev is None:
+            self.snapshot()
+        self._snap.warm()
 
     def init_carry(self, params: dict):
         """The daemon-lifetime arrival-history carry: the policy's encoder
@@ -751,7 +933,8 @@ class PlacementDaemon:
         # > 0: this many upcoming batches skip the Q-net launch and serve
         # from the kube heuristic (set on a deadline breach / NaN scores)
         self._degraded = 0
-        self._upload_nbytes = None   # snapshot + packed batch, device bytes
+        self._pods_nbytes = None     # a packed pod batch's device bytes
+        self._whole_nbytes = None    # a snapshot's, without ``last_publish``
         self.metrics = DaemonMetrics()
         self.decisions: List[Decision] = []
 
@@ -851,12 +1034,16 @@ class PlacementDaemon:
         return done
 
     def warmup(self) -> None:
-        """Prime the scoring compilation outside any timing window.
+        """Prime the scoring compilation, and the substrate's delta publish
+        where it has one, outside any timing window.
 
         ``n_real = 0``: every warmup row is a pad row, so a sequence
         policy's history carry is untouched by warming up.
         """
         jax.block_until_ready(self._scorer(*self._warm_args()))
+        warm_publish = getattr(self._sub, "warm_publish", None)
+        if warm_publish is not None:
+            warm_publish()
 
     def scorer_cache_size(self) -> int:
         """Compilations of the batched scorer (1 == every batch, at every
@@ -920,12 +1107,7 @@ class PlacementDaemon:
             with _Span(m, "sched.pack"):
                 pods = self._sub.pack([r.pod for r in reqs],
                                       self.config.batch_size)
-            if self._upload_nbytes is None:
-                # static shapes (one compilation), so the same bytes every
-                # batch; ``nbytes`` of a device array costs microseconds
-                self._upload_nbytes = sum(
-                    x.nbytes for x in jax.tree.leaves((snap, pods)))
-            m.upload_bytes += self._upload_nbytes
+            self._count_upload(snap, pods)
             t0 = self._timer()
             with _Span(m, "sched.launch"):
                 q, okq, carry2 = self._scorer(
@@ -974,6 +1156,29 @@ class PlacementDaemon:
                 else:
                     decided += self._commit(req, scores[i], ok[i], now)
         return decided
+
+    def _count_upload(self, snap, pods) -> None:
+        """Count the batch's host-to-device bytes and its publish's kind.
+
+        A substrate without ``last_publish`` publishes its whole snapshot.
+        Shapes are static (one compilation), so the pod batch's bytes, and
+        such a snapshot's, are taken once: ``nbytes`` of a device array
+        costs microseconds."""
+        m = self.metrics
+        if self._pods_nbytes is None:
+            self._pods_nbytes = sum(x.nbytes for x in jax.tree.leaves(pods))
+        pub = getattr(self._sub, "last_publish", None)
+        if pub is None:
+            if self._whole_nbytes is None:
+                self._whole_nbytes = sum(x.nbytes
+                                         for x in jax.tree.leaves(snap))
+            pub = _Publish(True, 0, self._whole_nbytes)
+        m.upload_bytes += self._pods_nbytes + pub.nbytes
+        if pub.full:
+            m.full_publishes += 1
+        else:
+            m.delta_publishes += 1
+            m.publish_rows += pub.rows
 
     def _diverged(self, real: np.ndarray) -> bool:
         """NaN or out-of-limit scores in the batch's real rows."""

@@ -5,7 +5,9 @@ size AND by max-wait; the whole batch scores in ONE device launch with ONE
 compilation across fill levels; racing binds to the same node resolve with
 exactly one winner and the loser re-validating against fresh state; the
 numpy live-buffer mirrors (``bind``/``feasible_one``) stay bit-close to the
-jnp references (``env.place``/``env.feasible``, ``PlacementEngine``); plus
+jnp references (``env.place``/``env.feasible``, ``PlacementEngine``); the
+resident snapshot's delta publish equals a whole publish bit for bit and
+compiles nothing after warm-up; plus
 the unified ``repro.sched.api`` dispatch, the arrival-trace adapter, the
 ``EpisodeResult`` shim, and ``serve.load_qnet`` checkpoint loading.
 """
@@ -26,8 +28,9 @@ from repro.core.types import (
 )
 from repro.launch.mesh import plan_fleet_layout
 from repro.scenarios import arrival_trace, trace_from_table
-from repro.sched import api, placement
+from repro.sched import api, placement, shard
 from repro.sched.daemon import (
+    DELTA_ROWS,
     ClusterSubstrate,
     DaemonConfig,
     FleetSubstrate,
@@ -815,17 +818,171 @@ class TestBatchLoopSpans:
         for _ in range(6):
             d.submit(pod)
         d.drain()
-        assert d.metrics.batches == 2
+        m = d.metrics
+        assert m.batches == 2
+        # the first batch publishes the whole (unpadded) state, the second
+        # only the rows the first one's binds changed, in one C-row buffer
+        assert (m.full_publishes, m.delta_publishes) == (1, 1)
+        assert 1 <= m.publish_rows <= 4
+        leaves = jax.tree.leaves(sub.live)
+        whole = sum(x.nbytes for x in leaves)
+        delta = min(DELTA_ROWS, CFG.n_nodes) * 4 * (
+            1 + sum(x.ndim for x in leaves))
         snap, pods = sub.snapshot(), sub.pack([pod], 4)
-        up = sum(x.nbytes for x in jax.tree.leaves((snap, pods)))
+        up = whole + delta + 2 * sum(x.nbytes for x in pods)
         out0, out1, _ = d._scorer(qparams, snap, pods, (), 1)
         back = np.asarray(out0).nbytes + np.asarray(out1).nbytes
         if layout == "flat":                  # (B, N) float32 scores + bools
             assert back == 4 * CFG.n_nodes * (4 + 1)
         else:                                 # (B, C) float32 + int32 lists
             assert back == 4 * 2 * 2 * (4 + 4)
-        assert d.metrics.upload_bytes == 2 * up
-        assert d.metrics.readback_bytes == 2 * back
+        assert m.upload_bytes == up
+        assert m.readback_bytes == 2 * back
+
+
+# C and C + 1 changed rows both fit; odd, so the 2-shard layout pads a row
+N_RESIDENT = DELTA_ROWS + 45
+
+
+def _resident_sub(layout):
+    cfg = dataclasses.replace(CFG, n_nodes=N_RESIDENT)
+    lay = None if layout == "flat" else plan_fleet_layout(N_RESIDENT,
+                                                          shards=2)
+    sub = ClusterSubstrate(kenv.reset(jax.random.PRNGKey(3), cfg), cfg,
+                           layout=lay, topk=2)
+    return sub, cfg
+
+
+def _bits(tree):
+    """Every leaf as a host copy of its bit pattern."""
+    out = []
+    for x in jax.tree.leaves(tree):
+        a = np.asarray(x)
+        out.append(a.view(f"u{a.itemsize}").copy())
+    return out
+
+
+def _same_bits(a, b):
+    return len(a) == len(b) and all(
+        x.shape == y.shape and x.dtype == y.dtype and np.array_equal(x, y)
+        for x, y in zip(a, b))
+
+
+class TestResidentSnapshot:
+    @pytest.mark.parametrize("layout", sorted(LAYOUTS))
+    def test_publish_equals_a_fresh_whole_publish(self, layout):
+        sub, cfg = _resident_sub(layout)
+        n, pod = cfg.n_nodes, kenv.default_pod(cfg)
+        rng = np.random.default_rng(14)
+
+        def rows(k):
+            return rng.choice(n, size=k, replace=False)
+
+        def binds():
+            for node in rows(5):
+                sub.bind(int(node), pod)
+
+        def unbinds():
+            for node in rows(3):
+                sub.unbind(int(node), pod)
+
+        def health():
+            sub.set_health(int(rng.integers(n)), bool(rng.integers(2)))
+
+        def direct():
+            lv = sub.live
+            r = rows(6)
+            lv.cpu_requested[r[:2]] = rng.normal(size=2)
+            lv.mem_used[r[2]] = np.nan
+            lv.pods_cpu[r[3]] = -0.0
+            lv.num_pods[r[4]] += 1
+            lv.image_cached[r[5]] = ~lv.image_cached[r[5]]
+
+        def replace():
+            sub.live = jax.tree.map(np.array, sub.live)
+            sub.live.startup_cpu[rows(2)] += 1.0
+
+        def many():
+            sub.live.uptime_hours[rows(DELTA_ROWS + 1)] += 1.0
+
+        def clock():
+            sub.live.time_s[()] += 0.5
+
+        def nothing():
+            pass
+
+        ops = [binds, unbinds, health, direct, replace, many, clock, nothing]
+        script = [nothing, binds, direct, nothing, many, binds, clock,
+                  unbinds, replace, health, binds]
+        script += [ops[i] for i in rng.integers(len(ops), size=40)]
+        kinds, held = set(), None
+        for op in script:
+            op()
+            snap = sub.snapshot()
+            pub = sub.last_publish
+            kinds.add("full" if pub.full else
+                      "delta" if pub.rows else "unchanged")
+            if op is nothing and held is not None:   # the mirror kept up
+                assert pub == (False, 0, 0)
+            whole = jax.tree.map(jnp.asarray, sub.live)
+            if sub.layout is not None:
+                whole = shard.shard_cluster(whole, sub.layout)
+            assert _same_bits(_bits(snap), _bits(whole)), op.__name__
+            # the previous snapshot is not changed by this publish
+            if held is not None:
+                assert _same_bits(_bits(held[0]), held[1]), op.__name__
+            held = (snap, _bits(snap))
+        assert kinds == {"full", "delta", "unchanged"}
+
+    def test_bitwise_change_detection(self):
+        sub, _ = _resident_sub("flat")
+        lv = sub.live
+        lv.mem_used[7] = 0.0
+        lv.mem_used[8] = np.nan
+        sub.snapshot()
+        lv.mem_used[7] = -0.0                 # == 0.0, but other bits
+        lv.mem_used[8] = np.nan               # same bits: no change
+        sub.snapshot()
+        assert sub.last_publish == (False, 1, sub.last_publish.nbytes)
+        assert np.signbit(np.asarray(sub.snapshot().mem_used)[7])
+
+    @pytest.mark.parametrize("layout", sorted(LAYOUTS))
+    def test_warm_publishes_compile_nothing_and_count(self, qparams, layout):
+        sub, cfg = _resident_sub(layout)
+        d = PlacementDaemon(sub, qparams,
+                            DaemonConfig(batch_size=2, max_wait_s=1e9),
+                            clock=FakeClock())
+        d.warmup()
+        # no node fits this pod: every request is dropped, nothing binds,
+        # and each publish sends exactly the rows written below
+        big = kenv.default_pod(cfg)._replace(cpu_request=jnp.float32(1e9))
+        events = []
+
+        def on_event(event, duration, **kw):
+            if "backend_compile" in event:
+                events.append(event)
+
+        jax.monitoring.register_event_duration_secs_listener(on_event)
+        try:
+            for k in (0, 1, DELTA_ROWS, DELTA_ROWS + 1):
+                sub.live.uptime_hours[:k] += 1.0
+                d.submit(big)
+                d.flush()
+            compiled = len(events)
+            jax.jit(lambda x: x * 3)(np.zeros(7, np.float32))
+        finally:
+            jax.monitoring.unregister_event_duration_listener(on_event)
+        assert compiled == 0 and len(events) == 1   # the listener listens
+        assert d.scorer_cache_size() == 1
+        assert [dec.node for dec in d.decisions] == [NO_PLACEMENT] * 4
+        m = d.metrics
+        assert (m.delta_publishes, m.full_publishes) == (3, 1)
+        assert m.publish_rows == 0 + 1 + DELTA_ROWS
+        leaves = jax.tree.leaves(sub.live)
+        whole = sum(x.nbytes for x in leaves)
+        buf = DELTA_ROWS * 4 * (1 + sum(x.ndim for x in leaves))
+        pods = sum(x.nbytes for x in sub.pack([big], 2))
+        assert m.upload_bytes == 4 * pods + 2 * buf + whole
 
 
 def _scorer_variants():

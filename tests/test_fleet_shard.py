@@ -254,7 +254,7 @@ class TestDeviceMeshParity:
         from repro.core import dqn, env as kenv
         from repro.core.types import fleet_cluster
         from repro.launch.mesh import plan_fleet_layout
-        from repro.sched import api, placement
+        from repro.sched import api, placement, shard
         from repro.sched.daemon import (ClusterSubstrate, DaemonConfig,
                                         PlacementDaemon)
 
@@ -285,15 +285,25 @@ class TestDeviceMeshParity:
             fleet, job, params=params, shard=False))
         nodes = {}
         for label, lay in (("flat", None), ("mesh", layout)):
-            d = PlacementDaemon(ClusterSubstrate(state, cfg, layout=lay),
-                                params, DaemonConfig(batch_size=3,
-                                                     max_wait_s=1e9),
+            sub = ClusterSubstrate(state, cfg, layout=lay)
+            d = PlacementDaemon(sub, params, DaemonConfig(batch_size=3,
+                                                          max_wait_s=1e9),
                                 clock=lambda: 0.0)
+            d.warmup()
             for _ in range(6):
                 d.submit(pod)
             d.drain()
             nodes[label] = [dec.node for dec in d.decisions]
         checks["daemon"] = nodes["mesh"] == nodes["flat"]
+        # on the mesh too, each batch publishes only its changed rows, the
+        # result equals a whole publish, and the scorer compiled once
+        whole = shard.shard_cluster(jax.tree.map(jnp.asarray, sub.live),
+                                    layout)
+        checks["daemon_resident"] = (
+            d.metrics.delta_publishes == 2 and d.scorer_cache_size() == 1
+            and all(np.array_equal(a, b) and a.sharding == b.sharding
+                    for a, b in zip(jax.tree.leaves(sub.snapshot()),
+                                    jax.tree.leaves(whole))))
         print("PARITY" + json.dumps(checks))
     """)
 
@@ -313,7 +323,7 @@ class TestDeviceMeshParity:
         line = [ln for ln in out.stdout.splitlines()
                 if ln.startswith("PARITY")][-1]
         checks = json.loads(line[len("PARITY"):])
-        assert len(checks) == 7 and all(checks.values()), checks
+        assert len(checks) == 8 and all(checks.values()), checks
 
 
 class TestGatesManifest:

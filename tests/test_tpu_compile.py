@@ -3,8 +3,10 @@
 Nothing here runs on a chip: the TPU compiler compiles for a v5e that is
 described, not attached, and refuses what the chip's compiler would refuse
 (block shapes off the (8, 128) tiling, too much VMEM, unsupported Mosaic
-ops).  Each test asserts that the compiled program holds a Mosaic kernel
-(``tpu_custom_call``), so an XLA fallback cannot pass for the kernel.
+ops).  Each kernel test asserts that the compiled program holds a Mosaic
+kernel (``tpu_custom_call``), so an XLA fallback cannot pass for the kernel.
+The daemon's delta publish, a plain XLA scatter, is compiled at the cells'
+sizes too.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
@@ -20,7 +22,12 @@ from repro.core.types import fleet_cluster
 from repro.kernels import ops
 from repro.launch.mesh import plan_fleet_layout
 from repro.sched import api, placement
-from repro.sched.daemon import ClusterSubstrate, FleetSubstrate
+from repro.sched.daemon import (
+    DELTA_ROWS,
+    ClusterSubstrate,
+    FleetSubstrate,
+    _scatter_rows,
+)
 
 SIZES = (5000, 131072)       # upstream's large-cluster node limit; 128k fleet
 KERNELS = ("afterstate", "afterstate_topk", "cols", "cols_topk", "score")
@@ -198,3 +205,23 @@ def test_daemon_scorer_compiles(one_chip, pallas_default, substrate):
         _shapes(pods, one_chip), (),
         jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("n,shards", [(5000, None), (100000, 8)])
+def test_delta_publish_scatter_compiles(one_chip, n, shards):
+    """The snapshot's delta publish (one C-row scatter into the resident
+    columns) at the benchmark cells' sizes: 5,000 flat, 100,000 in 8
+    shards."""
+    cfg = fleet_cluster(n)
+    layout = plan_fleet_layout(n, shards=shards) if shards else None
+    sub = ClusterSubstrate(kenv.reset(jax.random.PRNGKey(0), cfg), cfg,
+                           layout=layout)
+    cols = [x for x in jax.tree.leaves(sub.snapshot()) if x.ndim]
+    buf = jax.ShapeDtypeStruct((DELTA_ROWS, 1 + len(cols)), jnp.int32,
+                               sharding=one_chip)
+    compiled = _scatter_rows.lower(_shapes(cols, one_chip), buf,
+                                   layout).compile()
+    assert "scatter" in compiled.as_text()
+    out = jax.eval_shape(_scatter_rows, cols, buf, layout)
+    assert [(x.shape, x.dtype) for x in out] == [(x.shape, x.dtype)
+                                                 for x in cols]
