@@ -7,8 +7,9 @@ For each seed, in one process: one run of the cell as ``bench/run.py``
 makes it (a short window at the cell's own load), the numbers the check
 compares, and the control's numbers on the same sampled batches -- the
 reference put in the program's place in bfloat16, the precision below the
-configuration's float32.  One JSON line a seed; the benchmark's own runs
-never run this.  Without a TPU it exits 2.
+configuration's float32, by the configuration's own reference module.  One
+JSON line a seed; the benchmark's own runs never run this.  Without a TPU,
+or with a program that lacks what the reference states, it exits 2.
 """
 import time
 
@@ -36,16 +37,23 @@ def main(argv=None) -> int:
     config = harness.load_json(os.path.join(harness.ROOT, conf["file"]))
     mix = harness.load_json(os.path.join(harness.ROOT, "bench", "traffic",
                                          f"{cell['traffic']}.json"))
+    from bench.lib import reference, serve
+
+    ref = reference.for_config(config)
+    lacks = serve.program_lacks(ref)
+    if lacks:
+        return harness.fail(f"the program cannot run {conf['name']}: {lacks}")
     import jax
 
     harness.enable_compile_cache()
     if jax.devices()[0].platform != "tpu":
         return harness.fail("needs a TPU")
-    from bench.lib import check, serve
+    from bench.lib import check
 
     for seed in args.seeds:
         t0 = time.perf_counter()
-        run = serve.run_cell(config, mix, seed, args.seconds, False, t0)
+        run = serve.run_cell(config, mix, seed, args.seconds, False, t0,
+                             ref=ref)
         numbers = check.serving_checks(run, config["limits"])
         print(json.dumps({
             "workload": cell["name"], "seed": seed,

@@ -2,4 +2,5 @@
 the plain reference, trace reduction, work counts and metric arithmetic.
 
 Nothing here imports the program under test except ``serve.py``, which
-drives it; the reference (``reference.py``) imports nothing of it."""
+drives it; the base reference (``reference.py``) and every configuration's
+own reference module import nothing of it."""

@@ -3,8 +3,8 @@
 Inputs are the benchmark's own start columns, weights and pod mix, the
 ordered binds and retirements of the run, the scorer outputs and the
 commit loop's decisions of a seeded sample of the window's batches, and the
-program's final live buffer.  The
-plain reference (``reference.py``) re-derives everything else.
+program's final live buffer.  The configuration's plain reference
+(``reference.py``, or the module its file names) re-derives everything else.
 
 Numbers compared, each against the limit the configuration file states:
 
@@ -39,8 +39,7 @@ from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
-from bench.lib import reference as ref
-from bench.lib.cluster import PodType
+from bench.lib import reference
 
 BIND, UNBIND = 0, 1
 # the smallest row scale a relative gap is taken against
@@ -50,16 +49,22 @@ EXACT = ("feasible_mismatch", "decision_errors", "bind_violations",
 ORDER = ("score_err", "choice_gap", "bind_gap") + EXACT
 
 
-def _type_of(row: Sequence[float], types: Sequence[PodType]) -> int:
-    for i, t in enumerate(types):
-        want = np.float32([t.cpu_request, t.cpu_demand, t.mem_request,
-                           t.mem_demand])
+def _pod_rows(types: Sequence, ref: reference.Reference) -> np.ndarray:
+    """(T, F) float32: each pod type as the program packs it, in the order
+    of ``ref.POD_FIELDS``."""
+    return np.float32([[getattr(t, f) for f in ref.POD_FIELDS]
+                       for t in types])
+
+
+def _type_of(row: Sequence[float], rows: np.ndarray) -> int:
+    for i, want in enumerate(rows):
         if np.array_equal(np.float32(row), want):
             return i
     return -1
 
 
-def replay(start_cols, types, phys, events, positions):
+def replay(start_cols, types, phys, events, positions,
+           ref: reference.Reference):
     """Replay the run; returns (replay, bind violations, columns at each
     requested event position)."""
     rp = ref.Replay(start_cols, types, phys)
@@ -86,7 +91,7 @@ class _Type:
     filter, the candidates the commit loop may walk, and the control's."""
 
     def __init__(self, cols, pod, phys, weights, scoring, precision,
-                 control):
+                 control, ref: reference.Reference):
         self.q = ref.afterstate_q(cols, pod, phys, weights)
         self.ok = ref.feasible(cols, pod)
         self.scale = (max(float(np.max(np.abs(self.q[self.ok]))), SCALE_FLOOR)
@@ -141,7 +146,8 @@ def _row_numbers(out_row, ty: _Type, scoring: dict) -> Tuple[float, float, int]:
     return err / scale, (best - float(qr[choice])) / scale, mism
 
 
-def _commit_numbers(s, cols, types, phys, get, control: bool
+def _commit_numbers(s, cols, types, phys, get, control: bool,
+                    ref: reference.Reference, rows: np.ndarray
                     ) -> Tuple[float, int]:
     """(bind_gap, decision_errors) of one sampled batch's commit loop.
 
@@ -155,7 +161,7 @@ def _commit_numbers(s, cols, types, phys, get, control: bool
     rp = ref.Replay(cols, types, phys)
     gap, errors = 0.0, 0
     left = collections.Counter(
-        _type_of(s["pods"][r], types) for r in range(s["n_real"]))
+        _type_of(s["pods"][r], rows) for r in range(s["n_real"]))
     for t, node in s["decisions"]:
         left[t] -= 1
         ty = get(t)
@@ -187,15 +193,18 @@ def _commit_numbers(s, cols, types, phys, get, control: bool
 
 
 def sample_numbers(samples, at, types, phys, weights, scoring: dict,
-                   precision: str = "bf16", control: bool = False) -> dict:
+                   precision: str = "bf16", control: bool = False,
+                   ref: reference.Reference = reference.BASE) -> dict:
     """score_err, choice_gap, bind_gap, feasible_mismatch and
-    decision_errors over the sampled batches.
+    decision_errors over the sampled batches, by the configuration's
+    reference ``ref``.
 
     With ``control=True`` the scorer outputs and the commit loop's choices
     are replaced by the reference computed at ``precision`` (the control
     put in the program's place)."""
     err = gap = bgap = 0.0
     mism = derr = 0
+    rows = _pod_rows(types, ref)
     for s in samples:
         cols = at[s["pos"]]
         cache: Dict[int, _Type] = {}
@@ -203,11 +212,11 @@ def sample_numbers(samples, at, types, phys, weights, scoring: dict,
         def get(t):
             if t not in cache:
                 cache[t] = _Type(cols, types[t], phys, weights, scoring,
-                                 precision, control)
+                                 precision, control, ref)
             return cache[t]
 
         for r in range(s["n_real"]):
-            t = _type_of(s["pods"][r], types)
+            t = _type_of(s["pods"][r], rows)
             if t < 0:
                 mism += 1
                 continue
@@ -215,7 +224,8 @@ def sample_numbers(samples, at, types, phys, weights, scoring: dict,
             row = ty.ctrl_row if control else (s["out0"][r], s["out1"][r])
             e, g, m = _row_numbers(row, ty, scoring)
             err, gap, mism = max(err, e), max(gap, g), mism + m
-        b, d = _commit_numbers(s, cols, types, phys, get, control)
+        b, d = _commit_numbers(s, cols, types, phys, get, control, ref,
+                               rows)
         bgap, derr = max(bgap, b), derr + d
     return {"score_err": err, "choice_gap": gap, "bind_gap": bgap,
             "feasible_mismatch": mism, "decision_errors": derr}
@@ -233,12 +243,13 @@ def accounting(decisions, n_submitted: int, metrics: dict) -> int:
 def serving_checks(run: dict, limits: Dict[str, float]
                    ) -> List[Tuple[str, float, float]]:
     """Every number compared, with its limit, in the order they print."""
-    cfg = run["config"]
+    cfg, ref = run["config"], run["ref"]
     positions = [s["pos"] for s in run["samples"]]
     rp, violations, at = replay(run["start_cols"], run["types"],
-                                cfg["physics"], run["events"], positions)
+                                cfg["physics"], run["events"], positions, ref)
     numbers = sample_numbers(run["samples"], at, run["types"],
-                             cfg["physics"], run["weights"], cfg["scoring"])
+                             cfg["physics"], run["weights"], cfg["scoring"],
+                             ref=ref)
     numbers.update({
         "bind_violations": violations,
         "state_diff": ref.state_diff(rp.cols, run["live_cols"]),
@@ -256,12 +267,12 @@ def serving_checks(run: dict, limits: Dict[str, float]
 def control_numbers(run: dict, precision: str = "bf16") -> Dict[str, float]:
     """score_err, choice_gap and bind_gap of the reference at ``precision``
     put in the program's place, on the run's own sampled batches."""
-    cfg = run["config"]
+    cfg, ref = run["config"], run["ref"]
     _, _, at = replay(run["start_cols"], run["types"], cfg["physics"],
-                      run["events"], [s["pos"] for s in run["samples"]])
+                      run["events"], [s["pos"] for s in run["samples"]], ref)
     nums = sample_numbers(run["samples"], at, run["types"], cfg["physics"],
                           run["weights"], cfg["scoring"], precision,
-                          control=True)
+                          control=True, ref=ref)
     return {k: nums[k] for k in ("score_err", "choice_gap", "bind_gap")}
 
 

@@ -27,7 +27,7 @@ from typing import Dict, Optional
 
 import numpy as np
 
-from bench.lib import cluster, trace as tracemod, traffic
+from bench.lib import cluster, reference, trace as tracemod, traffic
 from bench.lib.check import BIND, UNBIND
 
 # the scorer's jitted module, as the device trace names it
@@ -113,7 +113,24 @@ def _substrate_class():
     return BenchSubstrate
 
 
-def env_config(config: dict, n_nodes: int, first: cluster.PodType):
+def program_lacks(ref: reference.Reference) -> str:
+    """What the program lacks of the node columns and pod fields the
+    configuration's reference states (``"ClusterState lacks a, b; PodSpec
+    lacks c"``), or ``""``.  It imports the program's types only: no device
+    work, no compile."""
+    from repro.core.types import ClusterState, PodSpec
+
+    out = []
+    for kind, have, want in (("ClusterState", ClusterState._fields,
+                              ref.COLUMNS),
+                             ("PodSpec", PodSpec._fields, ref.POD_FIELDS)):
+        lacks = [f for f in want if f not in have]
+        if lacks:
+            out.append(f"{kind} lacks {', '.join(lacks)}")
+    return "; ".join(out)
+
+
+def env_config(config: dict, n_nodes: int, first: reference.PodType):
     from repro.core.types import EnvConfig
 
     phys = {k: v for k, v in config["physics"].items()
@@ -146,9 +163,13 @@ class _CompileCounter:
 
 
 def run_cell(config: dict, mix: dict, seed: int, seconds: float,
-             trace: bool, t_process: float, faults=None) -> Dict:
+             trace: bool, t_process: float, faults=None,
+             ref: Optional[reference.Reference] = None) -> Dict:
     """Set up, warm up, measure for ``seconds``, drain, check.  Returns the
-    run's record; the caller turns it into metrics.  ``faults`` (tests
+    run's record; the caller turns it into metrics.  ``ref`` is the
+    configuration's reference (``reference.for_config(config)`` where
+    None): the node columns, pod rows, weights and pre-fill are its, and
+    the record carries it to the check.  ``faults`` (tests
     only) patches the substrate or daemon after set-up, to show the check
     catches a broken timed path: ``{"scorer": f}`` wraps the program's
     scorer as ``f(scorer)`` underneath the benchmark's recorder, and
@@ -162,20 +183,21 @@ def run_cell(config: dict, mix: dict, seed: int, seconds: float,
 
     t_enter = time.perf_counter()
     traffic.check_mix(mix)
+    ref = reference.for_config(config) if ref is None else ref
     ss = np.random.SeedSequence(seed)
     r_cluster, r_prefill, r_order, r_stream, r_sample = (
         np.random.default_rng(s) for s in ss.spawn(5))
-    types = cluster.pod_types(config)
-    cols = cluster.reset(config, r_cluster)
+    types = ref.pod_types(config)
+    cols = ref.reset(config, r_cluster)
     fifo_init = cluster.prefill(cols, types, config["prefill"]["fill_frac"],
-                                cluster.PodStream(types, r_prefill), r_order)
+                                cluster.PodStream(types, r_prefill), r_order,
+                                ref)
     resident = len(fifo_init)
-    weights = cluster.config_weights(config)
+    weights = ref.config_weights(config)
     start_cols = {k: v.copy() for k, v in cols.items()}
     n_nodes = len(cols["cpu_capacity"])
     stream = cluster.PodStream(types, r_stream)
-    pods = [PodSpec(cpu_request=t.cpu_request, cpu_demand=t.cpu_demand,
-                    mem_request=t.mem_request, mem_demand=t.mem_demand)
+    pods = [PodSpec(**{f: getattr(t, f) for f in ref.POD_FIELDS})
             for t in types]
 
     t_cluster = time.perf_counter()
@@ -189,7 +211,7 @@ def run_cell(config: dict, mix: dict, seed: int, seconds: float,
     Sub = _substrate_class()
     Sub.recorder = rec
     state = ClusterState(time_s=np.float32(0.0),
-                         **{k: cols[k] for k in cluster.STATE_FIELDS})
+                         **{k: cols[k] for k in ref.COLUMNS})
     sub = Sub(state, env_config(config, n_nodes, types[0]), layout=layout,
               topk=scoring.get("topk", 8))
     params = {k: jnp.asarray(v) for k, v in weights.items()}
@@ -321,12 +343,13 @@ def run_cell(config: dict, mix: dict, seed: int, seconds: float,
                 daemon.decisions[rec.first_decision[call]:ends[call]]]
 
     samples = [{"pos": s["pos"], "n_real": s["n_real"],
-                "pods": np.stack([np.asarray(p) for p in s["pods"]], axis=1),
+                "pods": np.stack([np.asarray(getattr(s["pods"], f))
+                                  for f in ref.POD_FIELDS], axis=1),
                 "out0": np.asarray(s["out0"]), "out1": np.asarray(s["out1"]),
                 "decisions": batch_decisions(s["call"])}
                for s in rec.samples]
     run = {
-        "config": config, "types": types, "weights": weights,
+        "config": config, "ref": ref, "types": types, "weights": weights,
         "start_cols": start_cols, "events": events, "samples": samples,
         "live_cols": {k: np.array(v) for k, v in sub.live._asdict().items()},
         "decisions": list(daemon.decisions), "submitted": len(kind_of),

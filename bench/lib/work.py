@@ -5,7 +5,9 @@ node column that scoring and filtering read, once per batch; the batch's pod
 rows; the candidate lists the commit loop reads back; and for every (request,
 node) pair one afterstate-feature build, one filter and one evaluation of the
 6 -> 32 -> 1 Q-net.  They are not the bytes today's kernel happens to move.
-A multiply-add counts as two operations.
+A multiply-add counts as two operations.  The node columns, the pod row and
+the operations a pair are the configuration's reference's (``reference.py``);
+this module keeps the readback, the peaks and the least time.
 """
 from __future__ import annotations
 
@@ -13,42 +15,33 @@ import json
 import os
 from typing import Dict, Tuple
 
-# the node columns scoring (afterstate features) and filtering read, with
-# their widths in the snapshot: float32 and int32 columns 4 bytes, bools 1
-NODE_COLUMNS = {
-    "base_cpu": 4, "pods_cpu": 4, "startup_cpu": 4, "num_pods": 4,
-    "exp_pods": 4, "mem_used": 4, "image_cached": 1, "healthy": 1,
-    "uptime_hours": 4, "cpu_capacity": 4, "mem_capacity": 4, "max_pods": 4,
-    "cpu_requested": 4, "mem_requested": 4,
-}
-POD_ROW_BYTES = 4 * 4            # cpu/mem request and demand, float32
-HIDDEN, FEATURES = 32, 6
-# one afterstate-feature build: start cost select, the pod/experiment
-# increments, crowding (2), the raw CPU sum (7), utilization, contention knee
-# (2), contention (4), the cap, and six normalized features (6)
-FEATURE_FLOPS = 26
-FILTER_FLOPS = 5                 # two request sums, three comparisons (+ Ready)
-# 6 -> 32 multiply-adds, bias, ReLU, 32 -> 1 multiply-adds, bias
-MLP_FLOPS = 2 * FEATURES * HIDDEN + HIDDEN + HIDDEN + 2 * HIDDEN + 1
+from bench.lib import reference
+
+# the base configuration's counts; a configuration's reference states its own
+FEATURE_FLOPS = reference.Reference.FEATURE_FLOPS
+FILTER_FLOPS = reference.Reference.FILTER_FLOPS
+MLP_FLOPS = reference.Reference.MLP_FLOPS
 
 PEAKS_PATH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "peaks.json")
 
 
-def node_bytes() -> int:
-    return sum(NODE_COLUMNS.values())
+def node_bytes(ref: reference.Reference = reference.BASE) -> int:
+    return ref.node_bytes()
 
 
-def serve_batch(n_nodes: int, n_real: int, candidates: int = 0
+def serve_batch(n_nodes: int, n_real: int, candidates: int = 0,
+                ref: reference.Reference = reference.BASE
                 ) -> Tuple[float, float]:
     """(operations, bytes) one scoring launch needs for ``n_real`` requests
-    over ``n_nodes`` nodes.  ``candidates`` > 0 is the two-stage path, whose
-    commit loop reads ``candidates`` (score, index) pairs a request; 0 is
-    the flat path, whose commit loop reads a score and a feasibility flag
-    for every node."""
-    flops = float(n_real) * n_nodes * (FEATURE_FLOPS + FILTER_FLOPS + MLP_FLOPS)
+    over ``n_nodes`` nodes, by the configuration's reference ``ref``: its
+    node columns, pod row and operations a (request, node) pair.
+    ``candidates`` > 0 is the two-stage path, whose commit loop reads
+    ``candidates`` (score, index) pairs a request; 0 is the flat path, whose
+    commit loop reads a score and a feasibility flag for every node."""
+    flops = float(n_real) * n_nodes * ref.pair_flops()
     per_req = candidates * (4 + 4) if candidates else n_nodes * (4 + 1)
-    nbytes = float(n_nodes * node_bytes() + n_real * POD_ROW_BYTES
+    nbytes = float(n_nodes * ref.node_bytes() + n_real * ref.POD_ROW_BYTES
                    + n_real * per_req)
     return flops, nbytes
 

@@ -5,10 +5,11 @@
 
 The cell (an entry of ``workloads`` in ``BENCHMARK.json``) names a cluster
 configuration (``bench/configs/<config>.json``) and a traffic mix
-(``bench/traffic/<traffic>.json``).  One run builds the cluster, the pod
-stream and the Q-net weights from ``--seed``, warms up every shape the
-window uses, measures for ``--seconds``, drains, and checks what the timed
-path produced against the plain reference (``bench/lib/check.py``).
+(``bench/traffic/<traffic>.json``); the configuration names its plain
+reference (``bench/lib/reference.py`` where it names none).  One run builds
+the cluster, the pod stream and the Q-net weights from ``--seed``, warms up
+every shape the window uses, measures for ``--seconds``, drains, and checks
+what the timed path produced against the reference (``bench/lib/check.py``).
 
 ``--trace 0`` reports the cell's end-to-end metrics; ``--trace 1`` runs the
 window under the profiler and reports the per-layer metrics, each computed
@@ -17,10 +18,11 @@ output is one JSON object: ``correct``, ``attempted``, ``failed``,
 ``metrics``, ``device``, (traced) ``breakdown``, and last ``checks``, each
 number compared beside its limit.  The same numbers end standard error.
 
-Without a TPU, or with fewer chips than the cell asks for, it exits 2 and
-prints no result.  JAX's persistent compilation cache goes to
-``JAX_COMPILATION_CACHE_DIR`` when that is set, else to the fixed directory
-``<checkout>/.jax_cache``.
+Without a TPU, with fewer chips than the cell asks for, or with a program
+that lacks a node column or pod field the reference states (checked before
+JAX touches a device), it exits 2 and prints no result.  JAX's persistent
+compilation cache goes to ``JAX_COMPILATION_CACHE_DIR`` when that is set,
+else to the fixed directory ``<checkout>/.jax_cache``.
 """
 import time
 
@@ -105,12 +107,20 @@ def main(argv=None) -> int:
         metrics_wanted = cell_metrics(spec, cell, bool(args.trace))
         readers = {m["name"]: metric_reader(m["name"])
                    for m in metrics_wanted}
-    except (OSError, KeyError, ValueError) as e:
+        from bench.lib import reference
+
+        ref = reference.for_config(config)
+    except (OSError, KeyError, ValueError, ImportError) as e:
         return fail(f"cannot load the cell: {e}")
     try:
         import repro  # noqa: F401  (the system under test, from src/)
     except ImportError as e:
         return fail(f"the system under test is not in this checkout: {e}")
+    from bench.lib import serve
+
+    lacks = serve.program_lacks(ref)
+    if lacks:
+        return fail(f"the program cannot run {conf_entry['name']}: {lacks}")
 
     import jax
 
@@ -122,10 +132,10 @@ def main(argv=None) -> int:
         return fail(f"{cell['name']} needs {cell['chips']} chips, JAX found "
                     f"{len(devices)}")
 
-    from bench.lib import check, serve, work
+    from bench.lib import check, work
 
     run = serve.run_cell(config, mix, args.seed, args.seconds,
-                         bool(args.trace), T_PROCESS)
+                         bool(args.trace), T_PROCESS, ref=ref)
     if run["compiles_in_window"]:
         return fail(f"{run['compiles_in_window']} programs compiled inside "
                     f"the measured window", 3)

@@ -122,12 +122,12 @@ def test_every_metric_reader_loads(spec):
 
 def test_metric_readers_on_a_record():
     from bench import run as harness
-    from bench.lib import work
+    from bench.lib import reference, work
 
     run = {
         "setup_s": 12.5, "window_s": 10.0, "window_bound": 25_000,
         "n_nodes": 5000,
-        "config": {"scoring": {"path": "flat"}},
+        "config": {"scoring": {"path": "flat"}}, "ref": reference.BASE,
         "spans": {"poll_s": [0.010, 0.014], "snapshot_s": [0.002, 0.002],
                   "score_s": [0.001, 0.003], "batch_sizes": [32, 32]},
         "window_conflicts": 30, "commit_attempts": 40,
