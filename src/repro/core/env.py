@@ -65,7 +65,21 @@ def _scenario_pool(scn) -> dict:
         "peak_watts": col(lambda c: c.peak_watts),
         "mtbf": col(lambda c: c.mtbf_s),
         "mttr": col(lambda c: c.mttr_s),
+        "accel_capacity": col(lambda c: c.accel_capacity, np.int32),
     }
+
+
+def has_accel(cfg: EnvConfig) -> bool:
+    """True when the scenario's pool or catalog has any accelerator.
+
+    A *static* (trace-time) property: only then does ``reset`` build the
+    accelerator columns and ``sample_pod_table`` the pods' requests, so a
+    CPU-only scenario traces exactly the programs it always did.
+    """
+    scn = cfg.scenario
+    return scn is not None and (
+        any(c.accel_capacity > 0 for c in scn.node_classes)
+        or any(p.accel_request > 0 for p in scn.pod_types))
 
 
 def node_watts(cfg: EnvConfig) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -144,6 +158,12 @@ def reset(key: jax.Array, cfg: EnvConfig) -> ClusterState:
         startup0 = jax.random.uniform(kr4, (n,), maxval=0.3 * cfg.image_pull_cost)
 
     fexp = exp_pods0.astype(jnp.float32)
+    accel = {}
+    if has_accel(cfg):
+        # randomized starts' resident pods are CPU-side stand-ins: they
+        # book no accelerators
+        accel = dict(accel_capacity=jnp.asarray(pool["accel_capacity"]),
+                     accel_requested=jnp.zeros((n,), jnp.int32))
     return ClusterState(
         cpu_capacity=cap,
         mem_capacity=mem_cap,
@@ -160,6 +180,7 @@ def reset(key: jax.Array, cfg: EnvConfig) -> ClusterState:
         startup_cpu=startup0,
         image_cached=cached0,
         time_s=jnp.float32(0.0),
+        **accel,
     )
 
 
@@ -269,6 +290,8 @@ def sample_pod_table(key: jax.Array, cfg: EnvConfig, n_pods: int) -> PodTable:
         cpu_demand=jnp.asarray([p.cpu_demand for p in scn.pod_types], jnp.float32),
         mem_request=jnp.asarray([p.mem_request for p in scn.pod_types], jnp.float32),
         mem_demand=jnp.asarray([p.mem_demand for p in scn.pod_types], jnp.float32),
+        accel_request=(jnp.asarray([p.accel_request for p in scn.pod_types],
+                                   jnp.int32) if has_accel(cfg) else None),
     )
     specs = jax.tree.map(lambda col: col[type_idx], by_type)
     return PodTable(specs=specs, dt_s=_arrival_gaps(k_dt, cfg, n_pods),
@@ -353,14 +376,32 @@ def normalize_features(feats: jnp.ndarray) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 
+def accel_ask(state: ClusterState, pod: PodSpec) -> Optional[jnp.ndarray]:
+    """The pod's accelerator request as int32, or None where it asks for
+    none.  Asking for accelerators on a state without an accelerator column
+    is an error, never a silent bind."""
+    if pod.accel_request is None:
+        return None
+    if state.accel_capacity is None:
+        raise ValueError("the pod requests accelerators but the cluster "
+                         "state has no accelerator column")
+    return jnp.asarray(pod.accel_request, jnp.int32)
+
+
 def feasible(state: ClusterState, pod: PodSpec, cfg: EnvConfig) -> jnp.ndarray:
-    """k8s predicates: Ready, CPU/mem requests fit, below max-pods. (N,) bool."""
-    return (
+    """k8s predicates: Ready, CPU/mem requests fit, below max-pods, and the
+    accelerators not yet requested cover the pod's (extended resources are
+    never overcommitted; int32, exact). (N,) bool."""
+    ok = (
         state.healthy
         & (state.cpu_requested + pod.cpu_request <= state.cpu_capacity)
         & (state.mem_requested + pod.mem_request <= state.mem_capacity)
         & (state.num_pods < state.max_pods)
     )
+    accel = accel_ask(state, pod)
+    if accel is not None:
+        ok = ok & (state.accel_requested + accel <= state.accel_capacity)
+    return ok
 
 
 # ---------------------------------------------------------------------------
@@ -399,6 +440,10 @@ def place(state: ClusterState, action: jnp.ndarray, pod: PodSpec, cfg: EnvConfig
     onehot_i = onehot.astype(jnp.int32)
     cold = jnp.logical_not(state.image_cached)[jnp.clip(action, 0, state.n_nodes - 1)]
     start_cost = jnp.where(cold, pull_cost_now(state, cfg), cfg.warm_start_cost)
+    accel = accel_ask(state, pod)
+    if accel is not None:
+        state = state._replace(
+            accel_requested=state.accel_requested + onehot_i * accel)
     return state._replace(
         num_pods=state.num_pods + onehot_i,
         exp_pods=state.exp_pods + onehot_i,
@@ -500,6 +545,10 @@ def remove_pod(state: ClusterState, node: jnp.ndarray, pod: PodSpec,
     c = jnp.asarray(count, jnp.float32)
     onehot = jax.nn.one_hot(node, state.n_nodes, dtype=jnp.float32) * c
     onehot_i = onehot.astype(jnp.int32)
+    accel = accel_ask(state, pod)
+    if accel is not None:
+        state = state._replace(
+            accel_requested=state.accel_requested - onehot_i * accel)
     return state._replace(
         num_pods=state.num_pods - onehot_i,
         exp_pods=state.exp_pods - onehot_i,
@@ -515,13 +564,44 @@ def remove_pod(state: ClusterState, node: jnp.ndarray, pod: PodSpec,
 # ---------------------------------------------------------------------------
 
 
-def ledger_init(n_slots: int) -> PodLedger:
-    """Empty expiry ledger with one slot per episode arrival (static shape)."""
+def ledger_init(n_slots: int, accel: bool = False) -> PodLedger:
+    """Empty expiry ledger with one slot per episode arrival (static shape).
+    ``accel``: the pods ask for accelerators, so the spec carries their
+    requests."""
     z = jnp.zeros((n_slots,), jnp.float32)
     return PodLedger(
         node=jnp.full((n_slots,), -1, jnp.int32),
         expiry_s=jnp.full((n_slots,), jnp.inf, jnp.float32),
-        spec=PodSpec(cpu_request=z, cpu_demand=z, mem_request=z, mem_demand=z),
+        spec=PodSpec(cpu_request=z, cpu_demand=z, mem_request=z, mem_demand=z,
+                     accel_request=(jnp.zeros((n_slots,), jnp.int32)
+                                    if accel else None)),
+    )
+
+
+def _release(state: ClusterState, ledger: PodLedger, mask: jnp.ndarray,
+             seg: jnp.ndarray) -> ClusterState:
+    """Hand back the resources of every ledger pod in ``mask`` to its node
+    (``seg``): one fused scatter-add (``segment_sum``) per column."""
+    n = state.n_nodes
+    w = mask.astype(jnp.float32)
+
+    def released(col):
+        return jax.ops.segment_sum(w * col, seg, num_segments=n)
+
+    mask_i = mask.astype(jnp.int32)
+    cnt = jax.ops.segment_sum(mask_i, seg, num_segments=n)
+    accel = accel_ask(state, ledger.spec)
+    if accel is not None:
+        state = state._replace(accel_requested=state.accel_requested
+                               - jax.ops.segment_sum(mask_i * accel, seg,
+                                                     num_segments=n))
+    return state._replace(
+        num_pods=state.num_pods - cnt,
+        exp_pods=state.exp_pods - cnt,
+        cpu_requested=state.cpu_requested - released(ledger.spec.cpu_request),
+        mem_requested=state.mem_requested - released(ledger.spec.mem_request),
+        pods_cpu=state.pods_cpu - released(ledger.spec.cpu_demand),
+        mem_used=state.mem_used - released(ledger.spec.mem_demand),
     )
 
 
@@ -543,7 +623,8 @@ def ledger_record(ledger: PodLedger, slot, action, expiry_s, pod: PodSpec) -> Po
 def retire_expired(state: ClusterState, ledger: PodLedger
                    ) -> Tuple[ClusterState, PodLedger, jnp.ndarray]:
     """Retire every ledger pod whose expiry has passed: release its CPU/mem
-    requests, compute demand, and pod slots on its node, and free the slot.
+    requests, compute demand, pod slots and accelerators on its node, and
+    free the slot.
 
     One fused scatter-add (``segment_sum`` over the ledger's node column) per
     resource column — O(K + N) with static shapes, so the scanned episode
@@ -553,21 +634,7 @@ def retire_expired(state: ClusterState, ledger: PodLedger
     """
     n = state.n_nodes
     done = (ledger.node >= 0) & (ledger.expiry_s <= state.time_s)
-    seg = jnp.clip(ledger.node, 0, n - 1)
-    w = done.astype(jnp.float32)
-
-    def released(col):
-        return jax.ops.segment_sum(w * col, seg, num_segments=n)
-
-    cnt = jax.ops.segment_sum(done.astype(jnp.int32), seg, num_segments=n)
-    state = state._replace(
-        num_pods=state.num_pods - cnt,
-        exp_pods=state.exp_pods - cnt,
-        cpu_requested=state.cpu_requested - released(ledger.spec.cpu_request),
-        mem_requested=state.mem_requested - released(ledger.spec.mem_request),
-        pods_cpu=state.pods_cpu - released(ledger.spec.cpu_demand),
-        mem_used=state.mem_used - released(ledger.spec.mem_demand),
-    )
+    state = _release(state, ledger, done, jnp.clip(ledger.node, 0, n - 1))
     ledger = ledger._replace(node=jnp.where(done, -1, ledger.node))
     return state, ledger, jnp.sum(done).astype(jnp.int32)
 
@@ -718,20 +785,7 @@ def evict_down_pods(state: ClusterState, ledger: PodLedger, q: RescheduleQueue,
     state = state._replace(healthy=healthy_base & jnp.logical_not(down))
     seg = jnp.clip(ledger.node, 0, n - 1)
     evict = (ledger.node >= 0) & down[seg]
-    w = evict.astype(jnp.float32)
-
-    def released(col):
-        return jax.ops.segment_sum(w * col, seg, num_segments=n)
-
-    cnt = jax.ops.segment_sum(evict.astype(jnp.int32), seg, num_segments=n)
-    state = state._replace(
-        num_pods=state.num_pods - cnt,
-        exp_pods=state.exp_pods - cnt,
-        cpu_requested=state.cpu_requested - released(ledger.spec.cpu_request),
-        mem_requested=state.mem_requested - released(ledger.spec.mem_request),
-        pods_cpu=state.pods_cpu - released(ledger.spec.cpu_demand),
-        mem_used=state.mem_used - released(ledger.spec.mem_demand),
-    )
+    state = _release(state, ledger, evict, seg)
     remaining = ledger.expiry_s - state.time_s
     ledger = ledger._replace(node=jnp.where(evict, -1, ledger.node))
     q, n_lost = _queue_push(q, evict, remaining, cap)
@@ -986,7 +1040,9 @@ def run_episode(
 
     keys = jax.random.split(k_act, n_pods)
     (state, ledger, q, acc, _), actions = jax.lax.scan(
-        sched_step, (state, ledger_init(n_pods if use_ledger else 1),
+        sched_step, (state, ledger_init(n_pods if use_ledger else 1,
+                                        pod_table.specs.accel_request
+                                        is not None),
                      reschedule_queue_init(requeue_cap), _acc_init(),
                      sel_carry0),
         (jnp.arange(n_pods), keys, pod_table.specs, pod_table.dt_s,

@@ -45,6 +45,13 @@ class ClusterState(NamedTuple):
     startup_cpu: jnp.ndarray     # (N,) transient startup/image-pull CPU, decays
     image_cached: jnp.ndarray    # (N,) bool — experiment image present on node
     time_s: jnp.ndarray          # () seconds since episode start
+    # accelerators as a Kubernetes extended resource (``nvidia.com/gpu``,
+    # ``aws.amazon.com/neuron``, ``google.com/tpu``): an integer count a
+    # node, never overcommitted, never shared.  ``None`` (the default) is a
+    # cluster without them: no leaves, so such a state traces exactly the
+    # programs a CPU-only cluster always traced.
+    accel_capacity: Optional[jnp.ndarray] = None   # (N,) int32
+    accel_requested: Optional[jnp.ndarray] = None  # (N,) int32 booked
 
     @property
     def n_nodes(self) -> int:
@@ -58,6 +65,9 @@ class PodSpec(NamedTuple):
     cpu_demand: jnp.ndarray    # millicores actually burned while running
     mem_request: jnp.ndarray   # MiB
     mem_demand: jnp.ndarray    # MiB
+    # accelerators the pod asks for in ``limits`` (int32); ``None`` asks for
+    # none.  Asking for any on a state without accelerators is an error.
+    accel_request: Optional[jnp.ndarray] = None
 
 
 # ---------------------------------------------------------------------------
@@ -96,6 +106,9 @@ class NodeClass:
     # every pre-chaos scenario bit-identical (see env.sample_failure_trace).
     mtbf_s: float = float("inf")
     mttr_s: float = 60.0
+    # accelerators a node of this class carries (an extended resource); a
+    # pool where every class and pod type says 0 has no accelerator column
+    accel_capacity: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -116,6 +129,7 @@ class PodType:
     mem_demand: float                 # MiB
     lifetime_mean_s: float = float("inf")  # mean running duration; inf = forever
     lifetime_cv: float = 0.0          # lognormal coefficient of variation
+    accel_request: int = 0            # accelerators asked for in limits
 
 
 @dataclasses.dataclass(frozen=True)

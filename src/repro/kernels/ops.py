@@ -164,8 +164,9 @@ def sdqn_topk_afterstate(state, pod, cfg, params, *, k: int = 4,
     candidate afterstates, scored AND reduced in-kernel.
 
     The per-shard stage of two-stage hierarchical scoring (``sched.shard``):
-    the k8s filtering phase (``env.feasible``) and the Q-net both run inside
-    the kernel, and only k candidates per shard ever reach HBM.  Infeasible
+    the k8s filtering phase (``env.feasible``, with the accelerator
+    predicate where the state carries accelerators) and the Q-net both run
+    inside the kernel, and only k candidates per shard ever reach HBM.  Infeasible
     nodes carry ``-inf``; ties break to the lowest index (``jnp.argmax``'s
     first-occurrence rule), so merging shard candidates reproduces the flat
     masked argmax exactly.  ``mode="ref"`` is the unfused oracle:
@@ -187,6 +188,13 @@ def sdqn_topk_afterstate(state, pod, cfg, params, *, k: int = 4,
     cols = cols + (state.cpu_requested, state.mem_requested)
     scalars = scalars.at[_ss._S_CPU_REQ].set(pod.cpu_request)
     scalars = scalars.at[_ss._S_MEM_REQ].set(pod.mem_request)
+    accel = kenv.accel_ask(state, pod)
+    if state.accel_capacity is not None:
+        # the extended-resource filter: two more columns, and the request
+        cols = cols + (state.accel_capacity, state.accel_requested)
+        if accel is not None:
+            scalars = scalars.at[_ss._S_ACCEL_REQ].set(
+                accel.astype(jnp.float32))
     if mode == "xla":
         return _ss.sdqn_score_afterstate_topk_xla(cols, scalars, w1, b1, w2,
                                                   k=k)

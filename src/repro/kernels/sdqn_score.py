@@ -97,6 +97,8 @@ _S_UPTIME_SCALE, _S_EXP_SCALE, _S_B2 = 9, 10, 11
 # the top-k variants also filter in-kernel, so the pod's *requests* (the k8s
 # filtering phase operates on requests, not demands) ride in the pack too
 _S_CPU_REQ, _S_MEM_REQ = 12, 13
+# ... and, where the state carries accelerators, the pod's accelerator count
+_S_ACCEL_REQ = 14
 _N_SCALARS = 16  # padded pack width
 
 
@@ -406,10 +408,13 @@ def _topk_specs(block_rows, rows):
              jax.ShapeDtypeStruct((1, g * _LANES), jnp.int32)])
 
 
-def _afterstate_topk_kernel(k, n_hidden, base_ref, pcpu_ref, scpu_ref,
-                            npod_ref, epod_ref, mem_ref, cached_ref,
-                            health_ref, up_ref, cap_ref, mcap_ref, mpod_ref,
-                            creq_ref, mreq_ref, pack_ref, ov_ref, oi_ref):
+def _afterstate_topk_kernel(k, n_hidden, *refs):
+    (base_ref, pcpu_ref, scpu_ref, npod_ref, epod_ref, mem_ref, cached_ref,
+     health_ref, up_ref, cap_ref, mcap_ref, mpod_ref, creq_ref,
+     mreq_ref) = refs[:14]
+    accel_refs = refs[14:-3]     # (accel_capacity, accel_requested) or ()
+    pack_ref, ov_ref, oi_ref = refs[-3:]
+
     def s(i):
         return pack_ref[0, i]
 
@@ -425,6 +430,11 @@ def _afterstate_topk_kernel(k, n_hidden, base_ref, pcpu_ref, scpu_ref,
           & (creq_ref[...] + s(_S_CPU_REQ) <= cap_ref[...])
           & (mreq_ref[...] + s(_S_MEM_REQ) <= mcap_ref[...])
           & (npod_ref[...] < mpod_ref[...]))
+    if accel_refs:
+        # extended resources are never overcommitted; counts are exact in
+        # float32 up to 2**24
+        acap_ref, areq_ref = accel_refs
+        ok = ok & (areq_ref[...] + s(_S_ACCEL_REQ) <= acap_ref[...])
     vals, ids = _iter_topk(jnp.where(ok, q, -jnp.inf),
                            _global_idx(q.shape[0]), k)
     ov_ref[...] = vals
@@ -435,8 +445,10 @@ def _afterstate_topk_kernel(k, n_hidden, base_ref, pcpu_ref, scpu_ref,
 def sdqn_score_afterstate_topk(
     node_cols: tuple,      # 14 x (N,): the 12 afterstate columns (see
     #                        ``sdqn_score_afterstate``) + cpu_requested,
-    #                        mem_requested (filtering-phase columns)
+    #                        mem_requested (filtering-phase columns); 16 with
+    #                        accel_capacity, accel_requested
     scalars: jnp.ndarray,  # (_N_SCALARS,) pack incl. _S_CPU_REQ/_S_MEM_REQ
+    #                        (and _S_ACCEL_REQ with 16 columns)
     w1: jnp.ndarray,
     b1: jnp.ndarray,
     w2: jnp.ndarray,
@@ -451,10 +463,13 @@ def sdqn_score_afterstate_topk(
     the kernel, so HBM traffic is O(G * 128) instead of O(N) — the full score
     vector never materializes.  Infeasible nodes score ``-inf``; an
     all-infeasible shard returns all ``-inf`` (the merge layer maps that to
-    the NO_PLACEMENT sentinel).
+    the NO_PLACEMENT sentinel).  The column count picks the variant: 16
+    columns add the accelerator predicate to the in-kernel filter.
     """
     if not 1 <= k <= _LANES:
         raise ValueError(f"k must be in [1, {_LANES}], got {k}")
+    if len(node_cols) not in (14, 16):
+        raise ValueError(f"14 or 16 node columns, got {len(node_cols)}")
     n = node_cols[0].shape[0]
     h = w1.shape[1]
     block_rows, rows = _tiling(n, block_n)
@@ -465,7 +480,7 @@ def sdqn_score_afterstate_topk(
     vals, idx = pl.pallas_call(
         functools.partial(_afterstate_topk_kernel, k, h),
         grid=(rows // block_rows,),
-        in_specs=[_col_spec(block_rows)] * 14 + [_SMEM_SPEC],
+        in_specs=[_col_spec(block_rows)] * len(node_cols) + [_SMEM_SPEC],
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
@@ -489,6 +504,8 @@ def sdqn_score_afterstate_topk_xla(node_cols: tuple, scalars: jnp.ndarray,
           & (cols[12] + scalars[_S_CPU_REQ] <= cols[9])
           & (cols[13] + scalars[_S_MEM_REQ] <= cols[10])
           & (cols[3] < cols[11]))
+    if len(cols) == 16:
+        ok = ok & (cols[15] + scalars[_S_ACCEL_REQ] <= cols[14])
     k = min(k, q.shape[0])
     return jax.lax.top_k(jnp.where(ok, q, -jnp.inf), k)
 

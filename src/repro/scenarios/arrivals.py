@@ -61,11 +61,13 @@ def trace_from_table(table, rate_per_s: float | None = None) -> ArrivalTrace:
         else:  # pure burst: spread at exactly the offered rate
             t = np.arange(len(t), dtype=np.float64) / rate_per_s
     specs = jax.tree.map(np.asarray, table.specs)
+    accel = specs.accel_request
     pods = [
         PodSpec(cpu_request=float(specs.cpu_request[i]),
                 cpu_demand=float(specs.cpu_demand[i]),
                 mem_request=float(specs.mem_request[i]),
-                mem_demand=float(specs.mem_demand[i]))
+                mem_demand=float(specs.mem_demand[i]),
+                accel_request=None if accel is None else int(accel[i]))
         for i in range(len(t))
     ]
     return ArrivalTrace(t_s=t, pods=pods)

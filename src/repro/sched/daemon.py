@@ -216,6 +216,8 @@ class DaemonMetrics:
     delta_publishes: int = 0  # snapshots published as their changed rows
     publish_rows: int = 0     # rows the delta publishes sent
     readback_bytes: int = 0  # bytes of scorer outputs copied to the host
+    accel_bound: int = 0      # accelerators the bound pods asked for
+    accel_conflicts: int = 0  # failed re-validations the accelerators failed
     # per batch-loop stage (``sched.batch``, ``sched.snapshot``, ...; see
     # ``_Span``): summed wall seconds and the number of times it ran
     stage_s: Dict[str, float] = dataclasses.field(default_factory=dict)
@@ -236,11 +238,12 @@ DaemonStats = DaemonMetrics
 
 class _Request:
     __slots__ = ("req_id", "pod", "t_submit", "t_enqueued", "attempts",
-                 "not_before")
+                 "not_before", "accel")
 
-    def __init__(self, req_id, pod, t_submit):
+    def __init__(self, req_id, pod, t_submit, accel=0):
         self.req_id = req_id
         self.pod = pod
+        self.accel = accel           # accelerators the pod asks for
         self.t_submit = t_submit
         self.t_enqueued = t_submit   # last (re)entry into the queue
         self.attempts = 0
@@ -292,6 +295,11 @@ class _Publish(NamedTuple):
     full: bool     # the whole state; else only the rows changed since the last
     rows: int      # rows a delta publish sent (0 for a full one)
     nbytes: int    # host-to-device bytes
+
+
+def _accel_count(pod: PodSpec) -> int:
+    """Accelerators a pod asks for (0 where its spec leaves it at None)."""
+    return 0 if pod.accel_request is None else int(pod.accel_request)
 
 
 def _bit_view(a: np.ndarray) -> np.ndarray:
@@ -458,6 +466,9 @@ class ClusterSubstrate:
     ``last_publish`` says what the latest publish sent.
     ``bind``/``feasible_one`` mirror ``env.place``/``env.feasible``
     restricted to the touched row (parity pinned in tests/test_daemon.py).
+    Where the state carries accelerators (``accel_capacity``), every filter
+    and every bind and unbind here counts them too, and the delta publish
+    carries their two int32 columns.
     """
 
     def __init__(self, state: ClusterState, cfg: EnvConfig,
@@ -506,10 +517,34 @@ class ClusterSubstrate:
         def col(get):
             return jnp.asarray([float(get(p)) for p in pods], jnp.float32)
 
+        accel = None
+        if self.has_accel:
+            accel = jnp.asarray([_accel_count(p) for p in pods], jnp.int32)
         return PodSpec(cpu_request=col(lambda p: p.cpu_request),
                        cpu_demand=col(lambda p: p.cpu_demand),
                        mem_request=col(lambda p: p.mem_request),
-                       mem_demand=col(lambda p: p.mem_demand))
+                       mem_demand=col(lambda p: p.mem_demand),
+                       accel_request=accel)
+
+    @property
+    def has_accel(self) -> bool:
+        """The live state carries accelerator columns."""
+        return self.live.accel_capacity is not None
+
+    def accel_of(self, pod) -> int:
+        """Accelerators ``pod`` asks for; asking for any on a state without
+        accelerators is an error (admission's check)."""
+        a = _accel_count(pod)
+        if a and not self.has_accel:
+            raise ValueError(f"the pod requests {a} accelerators but the "
+                             f"cluster state has no accelerator column")
+        return a
+
+    def accel_fits(self, node: int, pod) -> bool:
+        """The accelerator predicate alone, against the LIVE buffer."""
+        lv = self.live
+        return bool(lv.accel_requested[node] + _accel_count(pod)
+                    <= lv.accel_capacity[node])
 
     def make_scorer(self, fused) -> Callable:
         """Jitted ``(params, snapshot, pod_batch, carry, n_real) ->
@@ -624,6 +659,7 @@ class ClusterSubstrate:
             and lv.mem_requested[node] + float(pod.mem_request)
             <= lv.mem_capacity[node]
             and lv.num_pods[node] < lv.max_pods[node]
+            and (lv.accel_capacity is None or self.accel_fits(node, pod))
         )
 
     def bind(self, node: int, pod: PodSpec) -> None:
@@ -642,6 +678,8 @@ class ClusterSubstrate:
         lv.mem_used[node] += float(pod.mem_demand)
         lv.startup_cpu[node] += start
         lv.image_cached[node] = True
+        if lv.accel_capacity is not None:
+            lv.accel_requested[node] += _accel_count(pod)
 
     def unbind(self, node: int, pod: PodSpec) -> None:
         """Release one bound pod from the live buffer: ``env.remove_pod``
@@ -654,6 +692,8 @@ class ClusterSubstrate:
         lv.mem_requested[node] -= float(pod.mem_request)
         lv.pods_cpu[node] -= float(pod.cpu_demand)
         lv.mem_used[node] -= float(pod.mem_demand)
+        if lv.accel_capacity is not None:
+            lv.accel_requested[node] -= _accel_count(pod)
 
     def set_health(self, node: int, healthy: bool) -> None:
         """Flip one node's Ready condition in the live buffer (the health
@@ -677,6 +717,10 @@ class ClusterSubstrate:
               & (lv.cpu_requested[None, :] + creq <= lv.cpu_capacity[None, :])
               & (lv.mem_requested[None, :] + mreq <= lv.mem_capacity[None, :])
               & (lv.num_pods[None, :] < lv.max_pods[None, :]))
+        if lv.accel_capacity is not None:
+            areq = np.asarray([_accel_count(p) for p in pods])[:, None]
+            ok &= (lv.accel_requested[None, :] + areq
+                   <= lv.accel_capacity[None, :])
         return q, ok
 
 
@@ -918,6 +962,10 @@ class PlacementDaemon:
         self._timer = timer
         self._pending: collections.deque = collections.deque()
         self._scorer = substrate.make_scorer(config.fused)
+        # admission's accelerator check (substrates without accelerators
+        # have none); the commit loop's accelerator predicate, set a batch
+        self._accel_of = getattr(substrate, "accel_of", None)
+        self._accel_fits = None
         # sharded substrates score to (B, C) candidate lists (two-stage
         # top-k merge) instead of full (B, N) rows — the commit path reads
         # candidates in merged order and never sees a fleet-length vector
@@ -941,7 +989,8 @@ class PlacementDaemon:
     # -- admission (writes the live buffer side only) -----------------------
 
     def submit(self, pod, now: Optional[float] = None) -> int:
-        """Enqueue one placement request; returns its request id.
+        """Enqueue one placement request; returns its request id.  A pod
+        that asks for accelerators on a state without them raises here.
 
         With ``queue_cap`` set, admission applies backpressure: a full queue
         sheds its OLDEST pending request (decided as ``shed=True``, node
@@ -958,7 +1007,8 @@ class PlacementDaemon:
                                                old.attempts, shed=True))
                 self.metrics.shed_wait_s.append(lat)
                 self.metrics.shed += 1
-        req = _Request(self._next_id, pod, now)
+        accel = 0 if self._accel_of is None else self._accel_of(pod)
+        req = _Request(self._next_id, pod, now, accel)
         self._next_id += 1
         self._pending.append(req)
         self.metrics.submitted += 1
@@ -1097,6 +1147,9 @@ class PlacementDaemon:
 
     def _score_and_commit(self, reqs: List[_Request], now: float) -> int:
         m = self.metrics
+        self._accel_fits = (self._sub.accel_fits
+                            if getattr(self._sub, "has_accel", False)
+                            else None)
         scores = ok = cand_idx = None
         degraded = self.config.heuristic_only or self._degraded > 0
         if not degraded:
@@ -1201,6 +1254,7 @@ class PlacementDaemon:
             self.metrics.dropped += 1
         else:
             self.metrics.bound += 1
+            self.metrics.accel_bound += req.accel
             self._bound[req.req_id] = (node, req.pod)
         if self.decision_hook is not None:
             # O(1) host-side append inside the hook (sched.online's
@@ -1226,6 +1280,7 @@ class PlacementDaemon:
         # optimistic bind lost the race: the snapshot's winner was taken by
         # an earlier bind (or external churn) before this request's turn
         self.metrics.conflicts += 1
+        self._count_accel_conflict(choice, req)
         if self.config.conflict_policy == "next-best":
             walked = 0
             for cand in np.argsort(-masked)[1:]:
@@ -1237,6 +1292,7 @@ class PlacementDaemon:
                     self._sub.bind(int(cand), req.pod)
                     self._decide(req, int(cand))
                     return 1
+                self._count_accel_conflict(int(cand), req)
             self.metrics.walk_steps += walked
         return self._requeue_or_drop(req, now)
 
@@ -1260,6 +1316,7 @@ class PlacementDaemon:
             self._decide(req, choice)
             return 1
         self.metrics.conflicts += 1
+        self._count_accel_conflict(choice, req)
         if self.config.conflict_policy == "next-best":
             walked = 0
             for v, cand in zip(vals[1:], idx[1:]):
@@ -1271,8 +1328,16 @@ class PlacementDaemon:
                     self._sub.bind(int(cand), req.pod)
                     self._decide(req, int(cand))
                     return 1
+                self._count_accel_conflict(int(cand), req)
             self.metrics.walk_steps += walked
         return self._requeue_or_drop(req, now)
+
+    def _count_accel_conflict(self, node: int, req: _Request) -> None:
+        """After a failed re-validation of ``node``: count it in
+        ``accel_conflicts`` where the accelerator predicate failed too."""
+        if (self._accel_fits is not None
+                and not self._accel_fits(node, req.pod)):
+            self.metrics.accel_conflicts += 1
 
     def _requeue_or_drop(self, req: _Request, now: float) -> int:
         if req.attempts > self.config.max_retries:

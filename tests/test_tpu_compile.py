@@ -6,7 +6,9 @@ described, not attached, and refuses what the chip's compiler would refuse
 ops).  Each kernel test asserts that the compiled program holds a Mosaic
 kernel (``tpu_custom_call``), so an XLA fallback cannot pass for the kernel.
 The daemon's delta publish, a plain XLA scatter, is compiled at the cells'
-sizes too.
+sizes too, and so is the daemon's scorer over 100,000 nodes that carry
+accelerators.  A CPU-only cluster's scorer for the chip is pinned to the
+program it was before ``ClusterState`` had accelerator fields.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
@@ -18,7 +20,13 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import dqn, env as kenv, policy as pol
-from repro.core.types import fleet_cluster
+from repro.core.types import (
+    NodeClass,
+    PodType,
+    ScenarioConfig,
+    fleet_cluster,
+    scenario_env,
+)
 from repro.kernels import ops
 from repro.launch.mesh import plan_fleet_layout
 from repro.sched import api, placement
@@ -225,3 +233,73 @@ def test_delta_publish_scatter_compiles(one_chip, n, shards):
     out = jax.eval_shape(_scatter_rows, cols, buf, layout)
     assert [(x.shape, x.dtype) for x in out] == [(x.shape, x.dtype)
                                                  for x in cols]
+
+
+def _gpu_cluster(n):
+    """``n`` nodes of 8 accelerators, 192 vCPU and 2,048 GiB; pods of 1, 2,
+    4 and 8 accelerators (the ``eks-100k-gpu`` benchmark's shapes)."""
+    node = NodeClass("p5", n, cpu_capacity=192000.0,
+                     mem_capacity=2048 * 1024.0, accel_capacity=8)
+    pods = tuple(PodType(f"gpu{a}", w, 20000.0 * a, 12000.0 * a,
+                         204800.0 * a, 163840.0 * a, accel_request=a)
+                 for a, w in ((1, 0.5), (2, 0.1), (4, 0.1), (8, 0.3)))
+    return scenario_env(ScenarioConfig("gpu", (node,), pods))
+
+
+def test_daemon_scorer_with_accelerators_compiles(one_chip, pallas_default):
+    """The daemon's one-launch scorer over 100,000 nodes with accelerators,
+    in 8 shards, batch 32: the top-k kernel takes 16 columns and filters
+    the accelerators in-kernel; the delta publish scatters 16 columns."""
+    n, batch = 100000, 32
+    cfg = _gpu_cluster(n)
+    layout = plan_fleet_layout(n, shards=8)
+    sub = ClusterSubstrate(kenv.reset(jax.random.PRNGKey(0), cfg), cfg,
+                           layout=layout)
+    pods = sub.pack([kenv.default_pod(cfg)], batch)
+    assert pods.accel_request.dtype == jnp.int32
+    snap = sub.snapshot()
+    text = sub.make_scorer("auto").lower(
+        _shapes(PARAMS, one_chip), _shapes(snap, one_chip),
+        _shapes(pods, one_chip), (),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+    cols = [x for x in jax.tree.leaves(snap) if x.ndim]
+    assert len(cols) == 16
+    buf = jax.ShapeDtypeStruct((DELTA_ROWS, 1 + len(cols)), jnp.int32,
+                               sharding=one_chip)
+    compiled = _scatter_rows.lower(_shapes(cols, one_chip), buf,
+                                   layout).compile()
+    assert "scatter" in compiled.as_text()
+
+
+# sha256 of the daemon scorer's StableHLO for the chip (Pallas kernels, no
+# source locations) at the benchmark's CPU-only shapes, batch 32: the
+# programs from before ``ClusterState`` had accelerator fields.  A change to
+# what a CPU-only cluster's scorer computes must update them.
+CPU_ONLY_CHIP_DIGESTS = {
+    5000: "452b5205fd6985bef4fdbaa408e0b7ab9fca727d8f85a8e4eefa94b76e67fb38",
+    100000: "5c370b3429d7890aee7903171d6fbcab5ccb092ca2552f2d237a29012395c3b2",
+}
+
+
+@pytest.mark.parametrize("n,shards", [(5000, None), (100000, 8)])
+def test_cpu_only_daemon_scorer_is_the_program_it_was(one_chip,
+                                                      pallas_default, n,
+                                                      shards):
+    import hashlib
+
+    from jax._src import config as jax_config
+
+    cfg = fleet_cluster(n)
+    layout = plan_fleet_layout(n, shards=shards) if shards else None
+    sub = ClusterSubstrate(kenv.reset(jax.random.PRNGKey(0), cfg), cfg,
+                           layout=layout)
+    pods = sub.pack([kenv.default_pod(cfg)], 32)
+    with jax_config.traceback_in_locations_limit(0):
+        text = sub.make_scorer("auto").lower(
+            _shapes(PARAMS, one_chip), _shapes(sub.snapshot(), one_chip),
+            _shapes(pods, one_chip), (),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == \
+        CPU_ONLY_CHIP_DIGESTS[n]
