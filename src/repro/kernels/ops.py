@@ -158,8 +158,7 @@ def sdqn_score_afterstate(state, pod, cfg, params, *, mode: Optional[str] = None
 
 
 def sdqn_topk_afterstate(state, pod, cfg, params, *, k: int = 4,
-                         mode: Optional[str] = None, block_n: int = 1024,
-                         pull_cost=None):
+                         mode: Optional[str] = None, pull_cost=None):
     """((k,) scores, (k,) node indices): the feasible top-k of one shard's
     candidate afterstates, scored AND reduced in-kernel.
 
@@ -199,7 +198,6 @@ def sdqn_topk_afterstate(state, pod, cfg, params, *, k: int = 4,
         return _ss.sdqn_score_afterstate_topk_xla(cols, scalars, w1, b1, w2,
                                                   k=k)
     return _ss.sdqn_score_afterstate_topk(cols, scalars, w1, b1, w2, k=k,
-                                          block_n=block_n,
                                           interpret=(mode == "interpret"))
 
 
@@ -227,7 +225,7 @@ def sdqn_score_delta(cols, deltas, params, *, mode: Optional[str] = None,
 
 
 def sdqn_topk_delta(cols, deltas, params, *, k: int = 4,
-                    mode: Optional[str] = None, block_n: int = 1024,
+                    mode: Optional[str] = None,
                     ceilings=(88.0, 95.0, 100.0 + 1e-6)):
     """((k,) scores, (k,) host indices): feasible top-k of the column scorer.
 
@@ -255,5 +253,4 @@ def sdqn_topk_delta(cols, deltas, params, *, k: int = 4,
                                             b2, ceilings, k=k)
     return _ss.sdqn_score_cols_topk(tuple(cols), deltas, kenv.FEATURE_SCALE,
                                     w1, b1, w2, b2, ceilings, k=k,
-                                    block_n=block_n,
                                     interpret=(mode == "interpret"))

@@ -23,9 +23,10 @@ multiply-accumulate, no (N, 6) stack, no GEMM) used as the fused fallback on
 CPU/GPU backends and as the reference for the interpret-mode sweeps.
 The column kernels view each per-node column as (rows, 128) and stream
 (8·m, 128) tiles — the TPU's (8, 128) vreg tiling, so every block is dense
-in both sublanes and lanes.  The Q-net weights and the scalar pack ride in
-one SMEM vector, so the 6->H->1 MLP is unrolled as scalar-times-tile
-multiply-accumulates on the VPU.
+in both sublanes and lanes; the top-k kernels take a whole shard of up to
+16,384 nodes as one block, so they select from it once.  The Q-net weights
+and the scalar pack ride in one SMEM vector, so the 6->H->1 MLP is unrolled
+as scalar-times-tile multiply-accumulates on the VPU.
 """
 from __future__ import annotations
 
@@ -145,6 +146,32 @@ def _tiling(n: int, block_n: int):
     cap = -(-rows // _SUBLANES) * _SUBLANES
     block_rows = min(max(block_n // _LANES // _SUBLANES, 1) * _SUBLANES, cap)
     return block_rows, -(-rows // block_rows) * block_rows
+
+
+# Most rows in one top-k block.  The k selection rounds hold a block's
+# scores, node indices and not-yet-taken mask live at once, each one vreg
+# an (8, 128) tile, so the block is capped near the 100,000-node cells' 104
+# rows (a 12,500-node shard, 13 vregs an array) against spills from the
+# 64-vreg file.  VMEM does not bind under the cap: 16 double-buffered
+# float32 columns of 128 rows take 16 x 128 x 128 x 4 B x 2 = 2 MiB of
+# v5e's 16 MiB default scoped VMEM.
+_TOPK_BLOCK_ROWS = 128
+
+
+def _topk_tiling(n: int):
+    """(block_rows, padded_rows) of the top-k kernels' (rows, 128) view.
+
+    One block spans the whole padded column up to ``_TOPK_BLOCK_ROWS`` rows
+    (16,384 nodes); a longer column takes the fewest blocks of whole
+    multiples of 8 rows under the cap.  Each block runs the k selection
+    rounds once, so the block count is the number of selections a call
+    makes.
+    """
+    rows = -(-n // _LANES)
+    cap = -(-rows // _SUBLANES) * _SUBLANES
+    blocks = -(-cap // _TOPK_BLOCK_ROWS)
+    block_rows = -(-cap // (blocks * _SUBLANES)) * _SUBLANES
+    return block_rows, blocks * block_rows
 
 
 def _grid_cols(cols, padded_rows, pad_value=0.0):
@@ -344,8 +371,10 @@ _IDX_INF = 2**31 - 1
 
 
 def _tile_reduce(x, op):
-    """Reduce a (rows, 128) tile to (1, 1): over lanes, then sublanes."""
-    return op(op(x, axis=1, keepdims=True), axis=0, keepdims=True)
+    """Reduce a (rows, 128) tile to (1, 1): over sublanes, then lanes.
+    Sublanes first, so a tall tile folds elementwise across its vregs before
+    the one cross-lane reduction."""
+    return op(op(x, axis=0, keepdims=True), axis=1, keepdims=True)
 
 
 def _iter_topk(scores, idx, k: int):
@@ -398,50 +427,74 @@ def _global_idx(block_rows):
     return row * _LANES + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
 
 
-def _topk_specs(block_rows, rows):
-    """Out specs/shapes of the top-k kernels: one lane-dense (1, 128) row of
-    candidates per grid step."""
-    g = rows // block_rows
-    spec = pl.BlockSpec((1, _LANES), lambda i: (0, i))
-    return ([spec, spec],
-            [jax.ShapeDtypeStruct((1, g * _LANES), jnp.float32),
-             jax.ShapeDtypeStruct((1, g * _LANES), jnp.int32)])
+def _score_then_select(masked_scores, k, q_ref, ov_ref, oi_ref):
+    """The body of a top-k grid step: fill the block's scores scratch one
+    (8, 128) tile at a time, then run the k selection rounds once over the
+    whole block.  ``masked_scores(rows)`` gives the tile's feasible-masked
+    scores; scoring a tile at a time lets its working set stay in vregs."""
+    block_rows = q_ref.shape[0]
 
+    def tile(t, carry):
+        rows = pl.ds(pl.multiple_of(t * _SUBLANES, _SUBLANES), _SUBLANES)
+        q_ref[rows, :] = masked_scores(rows)
+        return carry
 
-def _afterstate_topk_kernel(k, n_hidden, *refs):
-    (base_ref, pcpu_ref, scpu_ref, npod_ref, epod_ref, mem_ref, cached_ref,
-     health_ref, up_ref, cap_ref, mcap_ref, mpod_ref, creq_ref,
-     mreq_ref) = refs[:14]
-    accel_refs = refs[14:-3]     # (accel_capacity, accel_requested) or ()
-    pack_ref, ov_ref, oi_ref = refs[-3:]
-
-    def s(i):
-        return pack_ref[0, i]
-
-    feats = _afterstate_norm_features(
-        base_ref[...], pcpu_ref[...], scpu_ref[...], npod_ref[...],
-        epod_ref[...], mem_ref[...], cached_ref[...], health_ref[...],
-        up_ref[...], cap_ref[...], mcap_ref[...], mpod_ref[...], s,
-    )
-    q = _qnet(feats, pack_ref, n_hidden) + s(_S_B2)
-    # k8s filtering phase, in-kernel (env.feasible): padded lanes arrive with
-    # healthy == 0 and capacity == 1, so they are masked right here
-    ok = ((health_ref[...] > 0.5)
-          & (creq_ref[...] + s(_S_CPU_REQ) <= cap_ref[...])
-          & (mreq_ref[...] + s(_S_MEM_REQ) <= mcap_ref[...])
-          & (npod_ref[...] < mpod_ref[...]))
-    if accel_refs:
-        # extended resources are never overcommitted; counts are exact in
-        # float32 up to 2**24
-        acap_ref, areq_ref = accel_refs
-        ok = ok & (areq_ref[...] + s(_S_ACCEL_REQ) <= acap_ref[...])
-    vals, ids = _iter_topk(jnp.where(ok, q, -jnp.inf),
-                           _global_idx(q.shape[0]), k)
+    jax.lax.fori_loop(0, block_rows // _SUBLANES, tile, 0)
+    vals, ids = _iter_topk(q_ref[...], _global_idx(block_rows), k)
     ov_ref[...] = vals
     oi_ref[...] = ids
 
 
-@functools.partial(jax.jit, static_argnames=("k", "block_n", "interpret"))
+def _topk_call(kernel, grids, block_rows, pack, k, interpret):
+    """Run a top-k kernel over its (rows, 128) column views: one grid step a
+    block, each emitting one lane-dense (1, 128) candidate row, merged to
+    the (k,) top-k."""
+    g = grids[0].shape[0] // block_rows
+    out_spec = pl.BlockSpec((1, _LANES), lambda i: (0, i))
+    vals, idx = pl.pallas_call(
+        kernel,
+        grid=(g,),
+        in_specs=[_col_spec(block_rows)] * len(grids) + [_SMEM_SPEC],
+        out_specs=[out_spec, out_spec],
+        out_shape=[jax.ShapeDtypeStruct((1, g * _LANES), jnp.float32),
+                   jax.ShapeDtypeStruct((1, g * _LANES), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((block_rows, _LANES), jnp.float32)],
+        interpret=interpret,
+    )(*grids, pack)
+    return _merge_topk(vals, idx, k)
+
+
+def _afterstate_topk_kernel(k, n_hidden, *refs):
+    col_refs = refs[:-4]         # 14 columns, 16 with accelerators
+    pack_ref, ov_ref, oi_ref, q_ref = refs[-4:]
+
+    def s(i):
+        return pack_ref[0, i]
+
+    def masked_scores(rows):
+        (base, pcpu, scpu, npod, epod, mem, cached, health, up, cap, mcap,
+         mpod, creq, mreq) = [r[rows, :] for r in col_refs[:14]]
+        feats = _afterstate_norm_features(
+            base, pcpu, scpu, npod, epod, mem, cached, health, up, cap, mcap,
+            mpod, s)
+        q = _qnet(feats, pack_ref, n_hidden) + s(_S_B2)
+        # k8s filtering phase, in-kernel (env.feasible): padded lanes arrive
+        # with healthy == 0 and capacity == 1, so they are masked right here
+        ok = ((health > 0.5)
+              & (creq + s(_S_CPU_REQ) <= cap)
+              & (mreq + s(_S_MEM_REQ) <= mcap)
+              & (npod < mpod))
+        if len(col_refs) == 16:
+            # extended resources are never overcommitted; counts are exact
+            # in float32 up to 2**24
+            acap, areq = (r[rows, :] for r in col_refs[14:])
+            ok = ok & (areq + s(_S_ACCEL_REQ) <= acap)
+        return jnp.where(ok, q, -jnp.inf)
+
+    _score_then_select(masked_scores, k, q_ref, ov_ref, oi_ref)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def sdqn_score_afterstate_topk(
     node_cols: tuple,      # 14 x (N,): the 12 afterstate columns (see
     #                        ``sdqn_score_afterstate``) + cpu_requested,
@@ -454,38 +507,32 @@ def sdqn_score_afterstate_topk(
     w2: jnp.ndarray,
     *,
     k: int = 4,
-    block_n: int = 1024,
     interpret: bool = False,
 ):
     """((k,) scores, (k,) indices): the shard's feasible top-k, in-kernel.
 
-    Each grid step reduces its block to k candidates before anything leaves
-    the kernel, so HBM traffic is O(G * 128) instead of O(N) — the full score
-    vector never materializes.  Infeasible nodes score ``-inf``; an
-    all-infeasible shard returns all ``-inf`` (the merge layer maps that to
-    the NO_PLACEMENT sentinel).  The column count picks the variant: 16
-    columns add the accelerator predicate to the in-kernel filter.
+    A grid step scores its block into a VMEM scratch and selects the
+    block's k candidates from it; only those leave the kernel, so the full
+    score vector never reaches HBM.  The block is the whole shard up to
+    16,384 nodes (``_topk_tiling``), so a shard is selected from once; a
+    longer column is cut into a few blocks whose candidate rows
+    ``_merge_topk`` merges.  Infeasible nodes score
+    ``-inf``; an all-infeasible shard returns all ``-inf`` (the merge layer
+    maps that to the NO_PLACEMENT sentinel).  The column count picks the
+    variant: 16 columns add the accelerator predicate to the in-kernel
+    filter.
     """
     if not 1 <= k <= _LANES:
         raise ValueError(f"k must be in [1, {_LANES}], got {k}")
     if len(node_cols) not in (14, 16):
         raise ValueError(f"14 or 16 node columns, got {len(node_cols)}")
     n = node_cols[0].shape[0]
-    h = w1.shape[1]
-    block_rows, rows = _tiling(n, block_n)
+    block_rows, rows = _topk_tiling(n)
     grids = _grid_cols(node_cols[:9], rows) + _grid_cols(
         node_cols[9:12], rows, pad_value=1.0) + _grid_cols(node_cols[12:], rows)
-    out_specs, out_shape = _topk_specs(block_rows, rows)
-
-    vals, idx = pl.pallas_call(
-        functools.partial(_afterstate_topk_kernel, k, h),
-        grid=(rows // block_rows,),
-        in_specs=[_col_spec(block_rows)] * len(node_cols) + [_SMEM_SPEC],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*grids, _pack(scalars, w1, b1, w2))
-    return _merge_topk(vals, idx, k)
+    return _topk_call(
+        functools.partial(_afterstate_topk_kernel, k, w1.shape[1]), grids,
+        block_rows, _pack(scalars, w1, b1, w2), k, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
@@ -511,23 +558,23 @@ def sdqn_score_afterstate_topk_xla(node_cols: tuple, scalars: jnp.ndarray,
 
 
 def _cols_topk_kernel(k, n_hidden, c0, c1, c2, c3, c4, c5, pack_ref, ov_ref,
-                      oi_ref):
-    cols = (c0, c1, c2, c3, c4, c5)
-    feats = [cols[f][...] + pack_ref[0, f] for f in range(6)]
-    q = _qnet(feats, pack_ref, n_hidden) + pack_ref[0, 6]
-    # PlacementEngine.feasible, in-kernel: healthy + post-delta ceilings on
-    # the cpu / mem / job-util percent columns (pack 7..9)
-    ok = ((c3[...] > 0.5)
-          & (feats[0] <= pack_ref[0, 7])
-          & (feats[1] <= pack_ref[0, 8])
-          & (feats[2] <= pack_ref[0, 9]))
-    vals, ids = _iter_topk(jnp.where(ok, q, -jnp.inf),
-                           _global_idx(q.shape[0]), k)
-    ov_ref[...] = vals
-    oi_ref[...] = ids
+                      oi_ref, q_ref):
+    def masked_scores(rows):
+        feats = [c[rows, :] + pack_ref[0, f]
+                 for f, c in enumerate((c0, c1, c2, c3, c4, c5))]
+        q = _qnet(feats, pack_ref, n_hidden) + pack_ref[0, 6]
+        # PlacementEngine.feasible, in-kernel: healthy + post-delta ceilings
+        # on the cpu / mem / job-util percent columns (pack 7..9)
+        ok = ((c3[rows, :] > 0.5)
+              & (feats[0] <= pack_ref[0, 7])
+              & (feats[1] <= pack_ref[0, 8])
+              & (feats[2] <= pack_ref[0, 9]))
+        return jnp.where(ok, q, -jnp.inf)
+
+    _score_then_select(masked_scores, k, q_ref, ov_ref, oi_ref)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "block_n", "interpret"))
+@functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def sdqn_score_cols_topk(
     cols: tuple,
     deltas: jnp.ndarray,
@@ -539,28 +586,19 @@ def sdqn_score_cols_topk(
     ceilings,          # (3,): max cpu_pct, max mem_pct, max job_util_pct
     *,
     k: int = 4,
-    block_n: int = 1024,
     interpret: bool = False,
 ):
-    """Per-shard feasible top-k of ``sdqn_score_cols``, reduced in-kernel."""
+    """Per-shard feasible top-k of ``sdqn_score_cols``, reduced in-kernel
+    (blocks as in ``sdqn_score_afterstate_topk``)."""
     if not 1 <= k <= _LANES:
         raise ValueError(f"k must be in [1, {_LANES}], got {k}")
-    n = cols[0].shape[0]
-    h = w1.shape[1]
-    block_rows, rows = _tiling(n, block_n)
+    block_rows, rows = _topk_tiling(cols[0].shape[0])
     # healthy (col 3) pads 0 -> infeasible; the rest pad 0 and stay finite
-    grids = _grid_cols(cols, rows)
-    out_specs, out_shape = _topk_specs(block_rows, rows)
-
-    vals, idx = pl.pallas_call(
-        functools.partial(_cols_topk_kernel, k, h),
-        grid=(rows // block_rows,),
-        in_specs=[_col_spec(block_rows)] * 6 + [_SMEM_SPEC],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*grids, _cols_pack(deltas, b2, w1 / scale[:, None], b1, w2, ceilings))
-    return _merge_topk(vals, idx, k)
+    return _topk_call(
+        functools.partial(_cols_topk_kernel, k, w1.shape[1]),
+        _grid_cols(cols, rows), block_rows,
+        _cols_pack(deltas, b2, w1 / scale[:, None], b1, w2, ceilings), k,
+        interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("k",))

@@ -164,7 +164,7 @@ def test_episode_never_overcommits_and_releases_exactly(chaos):
 
 # -- the top-k kernel ----------------------------------------------------------
 
-@pytest.mark.parametrize("n", [5, 1024, 3000])
+@pytest.mark.parametrize("n", [5, 1024, 3000, 12500, 40000])
 @pytest.mark.parametrize("accel", [1, 4, 8])
 def test_topk_kernel_filters_accelerators_like_its_twin_and_numpy(n, accel):
     cfg, state = _gpu_state(n, seed=n)
@@ -300,9 +300,10 @@ def test_cpu_only_counters_stay_zero():
 
 # sha256 of the daemon scorer's StableHLO (no source locations), flat at 64
 # nodes and two-stage at 512 in 4 shards, batch 8, for the backend-default
-# (XLA twin) and the interpret-mode Pallas paths: the digests of the program
-# from before ``ClusterState`` had accelerator fields.  A change to what a
-# CPU-only cluster's scorer computes must update them.
+# (XLA twin) and the interpret-mode Pallas paths: the programs
+# ``ClusterState``'s accelerator fields left as they were, the two-stage
+# interpret-mode one with the top-k kernel that selects once a shard.  A
+# change to what a CPU-only cluster's scorer computes must update them.
 CPU_ONLY_DIGESTS = {
     (64, None, "auto"):
         "dddde3ed687e1cefd68d7b22d9c8f1661300d94d53273474a9a85c770518db31",
@@ -311,7 +312,7 @@ CPU_ONLY_DIGESTS = {
     (512, 4, "auto"):
         "23dac2502cd6e05f916aff3ffe120a2d3f5ce22fede3c40209b074577ca97bef",
     (512, 4, "interpret"):
-        "55cb3f2b574ca309d733232116216cc3ba3e1318cc1b93d6717689fea60d7952",
+        "2a159bef6f1e538a66b0d9aa4ea882b75b71f2e3a6002feeff40f0494b4b8cbd",
 }
 
 

@@ -129,16 +129,20 @@ def test_sdqn_score_cols_sweep(n):
                                    rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("n", [5, 1024, 3000])
+@pytest.mark.parametrize("n", [5, 1024, 3000, 12500, 40000])
 @pytest.mark.parametrize("kind", ["afterstate", "cols"])
 @pytest.mark.parametrize("case", ["random", "ties", "none_feasible"])
 def test_sdqn_topk_kernels_match_twins(kind, n, case):
     """The in-kernel per-block top-k (interpret mode) emits exactly the XLA
     twin's ``lax.top_k`` candidates.  ``ties`` makes every feasible node
-    score alike, so the first-index rule decides within and across the
-    (8, 128)-tile blocks; with no feasible node both list the lowest
-    indices at ``-inf``.  ``n = 3000`` spans three blocks."""
+    score alike, so the first-index rule decides within a block and in the
+    merge of several; with no feasible node both list the lowest indices at
+    ``-inf``.  ``n = 12500`` is one block (a shard at 100,000 nodes in 8);
+    ``n = 40000`` is three, for either kernel.  From 12,500 nodes the first
+    feasible nodes straddle node 1,024, the edge of the (8, 128)-tile
+    blocks the kernels once selected from."""
     ties = case == "ties"
+    first_ok = 2 if n <= 3000 else 1022
     import dataclasses
 
     from repro.core import env as kenv
@@ -153,7 +157,7 @@ def test_sdqn_topk_kernels_match_twins(kind, n, case):
         if ties:  # identical nodes, with the first two made infeasible
             state = kenv.reset(jax.random.PRNGKey(10), fleet_cluster(n))
             state = state._replace(
-                healthy=jnp.ones((n,), bool).at[:2].set(False),
+                healthy=jnp.ones((n,), bool).at[:first_ok].set(False),
                 uptime_hours=jnp.full((n,), 10.0),
                 num_pods=jnp.zeros((n,), state.num_pods.dtype),
                 cpu_requested=jnp.full((n,), 500.0),
@@ -170,7 +174,7 @@ def test_sdqn_topk_kernels_match_twins(kind, n, case):
         if ties:
             fleet = fleet._replace(cpu_pct=jnp.full((n,), 50.0),
                                    uptime_hours=jnp.full((n,), 10.0),
-                                   healthy=jnp.ones((n,)).at[:2].set(0.0))
+                                   healthy=jnp.ones((n,)).at[:first_ok].set(0.0))
         if case == "none_feasible":
             fleet = fleet._replace(healthy=jnp.zeros((n,)))
         cols = placement.fleet_cols(fleet)
@@ -186,7 +190,34 @@ def test_sdqn_topk_kernels_match_twins(kind, n, case):
                                rtol=1e-5, atol=1e-5)
     if ties:  # the lowest feasible indices, in order
         assert np.asarray(idx)[:min(4, n - 2)].tolist() == list(
-            range(2, 2 + min(4, n - 2)))
+            range(first_ok, first_ok + min(4, n - 2)))
+
+
+@pytest.mark.parametrize("n", [1, 1000, 1024, 1025, 12500, 12544, 16384,
+                               16385, 32768, 40000, 100000, 131072, 1000000])
+def test_topk_blocks_are_the_fewest_under_the_row_cap(n):
+    """Every top-k block is whole (8, 128) tiles, at most the cap; no fewer
+    blocks would do; the padding is under a tile a block."""
+    from repro.kernels import sdqn_score as ss
+
+    block_rows, rows = ss._topk_tiling(n)
+    blocks = rows // block_rows
+    need = -(-n // 1024) * 8                   # rows of whole (8, 128) tiles
+    assert block_rows % 8 == 0 and rows == blocks * block_rows >= need
+    assert block_rows <= ss._TOPK_BLOCK_ROWS
+    assert (blocks - 1) * ss._TOPK_BLOCK_ROWS < need
+    assert rows - need < 8 * blocks
+
+
+def test_topk_blocks_at_the_cells_shapes():
+    """One block a 12,500-node shard (the 100,000-node cells' 8 shards);
+    40,000 nodes split in three, and the 4-chip path's 32,768-node shards
+    in two."""
+    from repro.kernels import sdqn_score as ss
+
+    assert ss._topk_tiling(12500) == (104, 104)
+    assert ss._topk_tiling(40000) == (112, 336)
+    assert ss._topk_tiling(32768) == (128, 256)
 
 
 class TestXlaPathsMatchOracles:

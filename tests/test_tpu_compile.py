@@ -7,8 +7,8 @@ ops).  Each kernel test asserts that the compiled program holds a Mosaic
 kernel (``tpu_custom_call``), so an XLA fallback cannot pass for the kernel.
 The daemon's delta publish, a plain XLA scatter, is compiled at the cells'
 sizes too, and so is the daemon's scorer over 100,000 nodes that carry
-accelerators.  A CPU-only cluster's scorer for the chip is pinned to the
-program it was before ``ClusterState`` had accelerator fields.
+accelerators.  A CPU-only cluster's scorer for the chip is pinned to its
+program text.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
@@ -146,6 +146,28 @@ def test_afterstate_kernel_compiles_under_trainer_vmap(one_chip,
         states, pods, params)
 
 
+@pytest.mark.parametrize("accel", [False, True])
+def test_topk_kernel_compiles_at_the_daemons_shape(one_chip, accel):
+    """The top-k kernel as the daemon's two-stage scorer maps it: over 32
+    requests and 8 shards of 12,500 nodes, one (104, 128) block a shard,
+    with 14 columns, or 16 where the nodes carry accelerators."""
+    n, shards, batch = 12500, 8, 32
+    cfg = _gpu_cluster(n) if accel else fleet_cluster(n)
+    state = jax.eval_shape(lambda: kenv.reset(jax.random.PRNGKey(0), cfg))
+    pod = kenv.default_pod(cfg)
+    if accel:
+        pod = pod._replace(accel_request=jnp.int32(2))
+
+    def score(states, pods, params):
+        return jax.vmap(lambda p: jax.vmap(
+            lambda s: ops.sdqn_topk_afterstate(s, p, cfg, params, k=8,
+                                               mode="pallas"))(states))(pods)
+
+    _assert_kernel(score, _shapes(state, one_chip, batch=(shards,)),
+                   _shapes(pod, one_chip, batch=(batch,)),
+                   _shapes(PARAMS, one_chip))
+
+
 @pytest.mark.parametrize("n", [64, 4096])
 def test_attention_policy_kernel_compiles(one_chip, n):
     spec = pol.get("attention")
@@ -275,11 +297,12 @@ def test_daemon_scorer_with_accelerators_compiles(one_chip, pallas_default):
 
 # sha256 of the daemon scorer's StableHLO for the chip (Pallas kernels, no
 # source locations) at the benchmark's CPU-only shapes, batch 32: the
-# programs from before ``ClusterState`` had accelerator fields.  A change to
-# what a CPU-only cluster's scorer computes must update them.
+# programs ``ClusterState``'s accelerator fields left as they were, the
+# two-stage one with the top-k kernel that selects once a shard.  A change
+# to what a CPU-only cluster's scorer computes must update them.
 CPU_ONLY_CHIP_DIGESTS = {
     5000: "452b5205fd6985bef4fdbaa408e0b7ab9fca727d8f85a8e4eefa94b76e67fb38",
-    100000: "5c370b3429d7890aee7903171d6fbcab5ccb092ca2552f2d237a29012395c3b2",
+    100000: "645694515f85a87a925e2219ceffaae71359dac74de182c2f2e26d8c2c6967bc",
 }
 
 
