@@ -43,6 +43,16 @@ def masked_argmax(key: jax.Array, scores: jnp.ndarray, ok: jnp.ndarray,
     return jnp.where(jnp.any(ok), choice, jnp.int32(kenv.NO_NODE))
 
 
+def with_embedding(feats: jnp.ndarray, embed=None) -> jnp.ndarray:
+    """``(..., F)`` afterstate rows with a sequence policy's history
+    ``embed`` appended to every row (``feats`` itself where it is None)."""
+    if embed is None:
+        return feats
+    return jnp.concatenate(
+        [feats, jnp.broadcast_to(embed, feats.shape[:-1] + embed.shape)],
+        axis=-1)
+
+
 def score_afterstates(qparams: dict, state: ClusterState, pod: PodSpec,
                       cfg: EnvConfig, score_fn=None,
                       fused: bool | str = "auto", policy=None,
@@ -97,30 +107,9 @@ def score_afterstates(qparams: dict, state: ClusterState, pod: PodSpec,
                                     pull_cost=pull_cost)   # (N, 6) raw
     feats = kenv.normalize_features(after)
     if policy is not None:
-        if embed is not None:
-            feats = jnp.concatenate(
-                [feats, jnp.broadcast_to(embed, feats.shape[:-1] + embed.shape)],
-                axis=-1)
-        return policy.score_set(qparams, feats)
+        return policy.score_set(qparams, with_embedding(feats, embed))
     fn = score_fn or dqn.qvalues
     return fn(qparams, feats)
-
-
-def score_afterstates_batch(qparams: dict, state: ClusterState, pods: PodSpec,
-                            cfg: EnvConfig, score_fn=None,
-                            fused: bool | str = "auto",
-                            policy=None) -> jnp.ndarray:
-    """(B, N) scores for a *batch* of candidate pods against one snapshot.
-
-    ``pods`` is a ``PodSpec`` whose fields carry a leading batch dim (B,).
-    The batch axis is vmapped over the shared per-pod dispatch, so under
-    ``jit`` the whole batch lowers to ONE device launch — this is the
-    serving daemon's batched scoring pass (``sched.daemon``).
-    """
-    return jax.vmap(
-        lambda p: score_afterstates(qparams, state, p, cfg, score_fn, fused,
-                                    policy=policy)
-    )(pods)
 
 
 def make_sdqn_selector(qparams: dict, cfg: EnvConfig, epsilon: float = 0.0,

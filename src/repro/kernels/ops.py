@@ -230,10 +230,10 @@ def sdqn_topk_delta(cols, deltas, params, *, k: int = 4,
     """((k,) scores, (k,) host indices): feasible top-k of the column scorer.
 
     The FleetState arm of per-shard top-k scoring: the
-    ``PlacementEngine.feasible`` predicates (healthy + post-delta cpu / mem /
+    ``placement.fleet_feasible`` predicates (healthy + post-delta cpu / mem /
     job-util ceilings) and the Q-net both run in-kernel, emitting only k
     candidates per shard.  ``ceilings`` are the three predicate bounds (the
-    default mirrors ``PlacementEngine``'s 88 / 95 / 100).
+    default mirrors ``placement.fleet_ceilings``' 88 / 95 / 100).
     """
     from repro.core import env as kenv
 

@@ -563,7 +563,7 @@ def _cols_topk_kernel(k, n_hidden, c0, c1, c2, c3, c4, c5, pack_ref, ov_ref,
         feats = [c[rows, :] + pack_ref[0, f]
                  for f, c in enumerate((c0, c1, c2, c3, c4, c5))]
         q = _qnet(feats, pack_ref, n_hidden) + pack_ref[0, 6]
-        # PlacementEngine.feasible, in-kernel: healthy + post-delta ceilings
+        # placement.fleet_feasible, in-kernel: healthy + post-delta ceilings
         # on the cpu / mem / job-util percent columns (pack 7..9)
         ok = ((c3[rows, :] > 0.5)
               & (feats[0] <= pack_ref[0, 7])

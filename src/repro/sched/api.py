@@ -126,21 +126,19 @@ def _fleet_mode(fused) -> Optional[str]:
                      f"got {fused!r}")
 
 
-def _fleet_policy_score(fleet: FleetState, delta: jnp.ndarray, params: dict,
-                        policy, embed=None) -> jnp.ndarray:
-    """FleetState scoring through a non-fusable policy class: assemble the
-    (N, 6) afterstate rows the column kernel would have built in-kernel,
-    append ``embed`` when the spec carries one, and hand the whole set to
-    ``policy.score_set``."""
-    from repro.core import env as kenv
+def _fleet_scores(fleet: FleetState, delta: jnp.ndarray, *, params: dict,
+                  fused="auto", policy=None, embed=None) -> jnp.ndarray:
+    """(N,) FleetState scores of the job whose afterstate ``delta`` is
+    given: the fused column kernel (``ops.sdqn_score_delta``) for the
+    default net and fusable specs, else ``policy.score_set`` over the
+    afterstate rows with ``embed`` appended."""
+    if policy is not None and not policy.fused_kernel:
+        return policy.score_set(
+            params, _placement.afterstate_rows(fleet, delta, embed))
+    from repro.kernels import ops
 
-    feats = (jnp.stack(_placement.fleet_cols(fleet), axis=-1)
-             + delta[None, :]) / kenv.FEATURE_SCALE
-    if embed is not None:
-        feats = jnp.concatenate(
-            [feats, jnp.broadcast_to(embed, feats.shape[:-1] + embed.shape)],
-            axis=-1)
-    return policy.score_set(params, feats)
+    return ops.sdqn_score_delta(_placement.fleet_cols(fleet), delta, params,
+                                mode=_fleet_mode(fused))
 
 
 def _fleet_size(fleet: Fleet) -> int:
@@ -202,14 +200,8 @@ def _score_raw(fleet: Fleet, pod: Workload, *, params: dict,
         if score_fn is not None:
             raise ValueError("score_fn is not supported on the FleetState "
                              "column-kernel path")
-        if policy is not None and not policy.fused_kernel:
-            return _fleet_policy_score(fleet, _placement.job_delta(pod),
-                                       params, policy, embed=embed)
-        from repro.kernels import ops
-
-        return ops.sdqn_score_delta(
-            _placement.fleet_cols(fleet), _placement.job_delta(pod), params,
-            mode=_fleet_mode(fused))
+        return _fleet_scores(fleet, _placement.job_delta(pod), params=params,
+                             fused=fused, policy=policy, embed=embed)
     raise TypeError(f"unsupported fleet type: {type(fleet).__name__}")
 
 
@@ -227,20 +219,12 @@ def score_batch(fleet: Fleet, pods: Workload, *, params: dict,
         if cfg is None:
             raise ValueError("cfg (EnvConfig) is required to score a "
                              "ClusterState fleet")
-        return schedulers.score_afterstates_batch(params, fleet, pods, cfg,
-                                                  score_fn=score_fn,
-                                                  fused=fused, policy=policy)
+        return jax.vmap(lambda p: schedulers.score_afterstates(
+            params, fleet, p, cfg, score_fn, fused, policy=policy))(pods)
     if isinstance(fleet, FleetState):
-        deltas = jnp.stack([_placement.job_delta(j) for j in pods])
-        if policy is not None and not policy.fused_kernel:
-            return jnp.stack([_fleet_policy_score(fleet, d, params, policy)
-                              for d in deltas])
-        from repro.kernels import ops
-
-        cols = _placement.fleet_cols(fleet)
-        mode = _fleet_mode(fused)
-        return jnp.stack([ops.sdqn_score_delta(cols, d, params, mode=mode)
-                          for d in deltas])
+        return jnp.stack([_fleet_scores(fleet, _placement.job_delta(j),
+                                        params=params, fused=fused,
+                                        policy=policy) for j in pods])
     raise TypeError(f"unsupported fleet type: {type(fleet).__name__}")
 
 
@@ -267,17 +251,17 @@ def topk(fleet: Fleet, pod: Workload, *, params: dict,
                            policy=policy, embed=embed)
     q = _score_raw(fleet, pod, params=params, cfg=cfg, fused=fused,
                    score_fn=score_fn, policy=policy, embed=embed)
-    ok = _feasible(fleet, pod, cfg, params)
+    ok = _feasible(fleet, pod, cfg)
     vals, idx = jax.lax.top_k(jnp.where(ok, q, -jnp.inf), max(1, min(k, n)))
     return vals, jnp.where(jnp.isfinite(vals), idx, -1)
 
 
-def _feasible(fleet: Fleet, pod: Workload, cfg, params: dict) -> jnp.ndarray:
+def _feasible(fleet: Fleet, pod: Workload, cfg) -> jnp.ndarray:
     if isinstance(fleet, ClusterState):
         from repro.core import env as kenv
 
         return kenv.feasible(fleet, pod, cfg)
-    return _placement.PlacementEngine(params).feasible(fleet, pod)
+    return _placement.fleet_feasible(fleet, _placement.job_delta(pod))
 
 
 def select(fleet: Fleet, pod: Workload, *, params: dict,
@@ -306,7 +290,7 @@ def select(fleet: Fleet, pod: Workload, *, params: dict,
                                         guard=guard)
     q = score(fleet, pod, params=params, cfg=cfg, fused=fused, shard=False,
               score_fn=score_fn, policy=policy, guard=guard)
-    ok = _feasible(fleet, pod, cfg, params)
+    ok = _feasible(fleet, pod, cfg)
     masked = jnp.where(ok, q, -jnp.inf)
     choice = jnp.argmax(masked).astype(jnp.int32)
     return jnp.where(jnp.any(ok), choice, jnp.int32(NO_PLACEMENT))

@@ -5,11 +5,14 @@ placement decisions under load.  This daemon is that serving loop:
 
   * **Batched one-launch scoring.**  Pending pod requests accumulate into
     batches (cut by size OR by the oldest request's wait time) and the whole
-    batch is scored through the shared fused dispatch
-    (``schedulers.score_afterstates_batch`` / ``ops.sdqn_score_delta`` via
-    ``repro.sched.api``) in ONE device launch — one jitted call per batch,
-    padded to a static batch shape so every fill level reuses one
-    compilation.
+    batch is scored in ONE device launch by one jitted driver
+    (``_batch_scorer``), padded to a static batch shape so every fill level
+    reuses one compilation.  Each substrate's ``make_scorer`` only picks the
+    per-request scorer the driver maps over the batch: the flat afterstate
+    dispatch (``schedulers.score_afterstates``, the column kernel for
+    fleets) with the filter, or the two-stage candidate merge of
+    ``sched.shard`` under a layout.  A sequence policy's encoder carry
+    advances inside the same launch.
   * **Double-buffered fleet state.**  Admission (``submit`` + committed
     binds) writes the *live* buffer — a mutable host-side (numpy) mirror —
     while scoring reads a frozen device *snapshot* published at batch cut.
@@ -40,9 +43,8 @@ bind/feasibility arithmetic is pinned against the jnp reference
     d.submit(pod); ...; d.poll(); decisions = d.decisions
 
 Offered load comes from the scenario engine's arrival streams
-(``scenarios.arrivals.arrival_trace``) replayed through ``replay_trace`` —
-see ``benchmarks/placement_serve.py`` for the sustained placements/sec and
-p50/p99 decision-latency bench.
+(``scenarios.arrivals.arrival_trace``) replayed through ``replay_trace``.
+The serving benchmark, measured on the chip, is ``bench/run.py``.
 """
 from __future__ import annotations
 
@@ -451,6 +453,46 @@ class _DeviceSnapshot:
         return _Publish(False, k, buf.nbytes)
 
 
+def _batch_scorer(per_request, workload_features, policy,
+                  shared=lambda snap: None):
+    """The one-launch batch scorer every substrate and layout serves with.
+
+    Returns the jitted ``score(params, snap, pods, carry, n_real) -> (a, b,
+    carry)``: ``per_request(params, snap, pod, embed, s) -> (a, b)`` run for
+    every request of the batch inside ONE device launch, where ``s =
+    shared(snap)`` is computed once a batch.  A stateless policy (``None``
+    or ``embed_dim == 0``) maps ``per_request`` over the batch with
+    ``embed=None`` and passes ``carry`` through untouched.  A sequence
+    policy scans the batch in submission order: each request's
+    ``workload_features`` advance the encoder carry, and ``per_request``
+    scores with that step's embedding.  ``n_real`` is a traced scalar; rows
+    at or past it are padding, scored (a static shape, one compilation at
+    every fill level) but never advancing the carry.
+    """
+    stateless = policy is None or policy.embed_dim == 0
+
+    @jax.jit
+    def score(params, snap, pods, carry, n_real):
+        s = shared(snap)
+        if stateless:
+            a, b = jax.vmap(
+                lambda pod: per_request(params, snap, pod, None, s))(pods)
+            return a, b, carry
+
+        def step(c, xs):
+            pod, is_real = xs
+            c2, emb = policy.encode_step(params, c, workload_features(pod))
+            c2 = jax.tree.map(lambda x, y: jnp.where(is_real, x, y), c2, c)
+            return c2, per_request(params, snap, pod, emb, s)
+
+        n_b = jax.tree.leaves(pods)[0].shape[0]
+        is_real = jnp.arange(n_b) < n_real
+        carry2, (a, b) = jax.lax.scan(step, carry, (pods, is_real))
+        return a, b, carry2
+
+    return score
+
+
 class ClusterSubstrate:
     """The paper's pod->node cluster as a daemon substrate.
 
@@ -547,106 +589,52 @@ class ClusterSubstrate:
                     <= lv.accel_capacity[node])
 
     def make_scorer(self, fused) -> Callable:
-        """Jitted ``(params, snapshot, pod_batch, carry, n_real) ->
-        (scores, feasible, carry)``, scores/feasible (B, N): the whole batch
-        in ONE device launch.
+        """The batch scorer (``_batch_scorer``): ``(params, snapshot,
+        pod_batch, carry, n_real) -> (scores, feasible, carry)``,
+        scores/feasible (B, N), the whole batch in ONE device launch.
 
         The signature is uniform across policy classes so the daemon loop
-        never branches: stateless specs thread ``carry = ()`` untouched,
-        sequence specs advance their encoder carry *inside* the launch via a
-        ``lax.scan`` over the batch (requests encode in submission order).
-        ``n_real`` is a traced scalar — the ``< n_real`` pad mask means pad
-        rows are scored (static shape, one compilation at every fill level)
-        but never advance the history.  A conflicted request that re-queues
+        never branches.  A sequence spec's encoder reads each pod's
+        ``pod_workload_features``.  A conflicted request that re-queues
         re-encodes on its next batch — the history sees it twice, which is
         faithful to a kube scheduling queue (the pod really does arrive at
         the scheduler again).
 
-        With a ``layout`` the contract becomes ``(params, snap, pods, carry,
-        n_real) -> (cand_vals, cand_idx, carry)``, both (B, C) with
-        ``C = shards * topk``: each request's two-stage candidate merge
-        (sorted descending, ``-inf``/``-1`` past the feasible set) — the full
-        (B, N) score matrix is never materialized on one device.  The
+        With a ``layout`` the outputs become ``(cand_vals, cand_idx,
+        carry)``, both (B, C) with ``C = shards * topk``: each request's
+        two-stage candidate merge (``shard.cluster_topk``; sorted
+        descending, ``-inf``/``-1`` past the feasible set) — the full (B, N)
+        score matrix is never materialized on one device.  The
         ``pull_cost_now`` global reduction is computed once per batch from
-        the sharded snapshot and threaded into every per-shard call.
+        the sharded snapshot and threaded into every request's call.
         """
-        cfg, score_fn, policy = self.cfg, self.score_fn, self.policy
-
-        if self.layout is not None:
-            from repro.core import policy as policy_mod
-            from repro.sched import shard as _shard
-
-            layout, k = self.layout, self.topk
-
-            if policy is None or policy.embed_dim == 0:
-
-                @jax.jit
-                def score(params, snap, pods, carry, n_real):
-                    pull = kenv.pull_cost_now(snap, cfg)
-                    cv, ci = jax.vmap(
-                        lambda p: _shard.cluster_topk(
-                            params, snap, p, cfg, layout, k=k, fused=fused,
-                            score_fn=score_fn, policy=policy,
-                            pull_cost=pull))(pods)
-                    return cv, ci, carry
-
-                return score
-
-            @jax.jit
-            def score(params, snap, pods, carry, n_real):
-                pull = kenv.pull_cost_now(snap, cfg)
-
-                def step(c, xs):
-                    pod, is_real = xs
-                    c2, emb = policy.encode_step(
-                        params, c, policy_mod.pod_workload_features(pod))
-                    c2 = jax.tree.map(lambda a, b: jnp.where(is_real, a, b),
-                                      c2, c)
-                    cv, ci = _shard.cluster_topk(
-                        params, snap, pod, cfg, layout, k=k, fused=fused,
-                        policy=policy, embed=emb, pull_cost=pull)
-                    return c2, (cv, ci)
-
-                n_b = jax.tree.leaves(pods)[0].shape[0]
-                is_real = jnp.arange(n_b) < n_real
-                carry2, (cv, ci) = jax.lax.scan(step, carry, (pods, is_real))
-                return cv, ci, carry2
-
-            return score
-
-        if policy is None or policy.embed_dim == 0:
-
-            @jax.jit
-            def score(params, snap, pods, carry, n_real):
-                q = schedulers.score_afterstates_batch(params, snap, pods,
-                                                       cfg, score_fn, fused,
-                                                       policy=policy)
-                ok = jax.vmap(lambda p: kenv.feasible(snap, p, cfg))(pods)
-                return q, ok, carry
-
-            return score
-
         from repro.core import policy as policy_mod
 
-        @jax.jit
-        def score(params, snap, pods, carry, n_real):
-            def step(c, xs):
-                pod, is_real = xs
-                c2, emb = policy.encode_step(
-                    params, c, policy_mod.pod_workload_features(pod))
-                c2 = jax.tree.map(lambda a, b: jnp.where(is_real, a, b),
-                                  c2, c)
+        cfg, score_fn, policy = self.cfg, self.score_fn, self.policy
+        if self.layout is None:
+
+            def per_request(params, snap, pod, embed, s):
                 q = schedulers.score_afterstates(params, snap, pod, cfg,
-                                                 fused=fused, policy=policy,
-                                                 embed=emb)
-                return c2, (q, kenv.feasible(snap, pod, cfg))
+                                                 score_fn, fused,
+                                                 policy=policy, embed=embed)
+                return q, kenv.feasible(snap, pod, cfg)
 
-            n_b = jax.tree.leaves(pods)[0].shape[0]
-            is_real = jnp.arange(n_b) < n_real
-            carry2, (q, ok) = jax.lax.scan(step, carry, (pods, is_real))
-            return q, ok, carry2
+            return _batch_scorer(per_request,
+                                 policy_mod.pod_workload_features, policy)
 
-        return score
+        from repro.sched import shard as _shard
+
+        layout, k = self.layout, self.topk
+
+        def per_request(params, snap, pod, embed, pull):
+            return _shard.cluster_topk(params, snap, pod, cfg, layout, k=k,
+                                       fused=fused, score_fn=score_fn,
+                                       policy=policy, embed=embed,
+                                       pull_cost=pull)
+
+        return _batch_scorer(per_request, policy_mod.pod_workload_features,
+                             policy,
+                             lambda snap: kenv.pull_cost_now(snap, cfg))
 
     def feasible_one(self, node: int, pod: PodSpec) -> bool:
         """``env.feasible`` row ``node`` against the LIVE buffer (bind-time
@@ -733,7 +721,7 @@ class FleetSubstrate:
     """
 
     def __init__(self, fleet: _pl.FleetState,
-                 max_host_cpu_pct: float = 88.0, policy=None,
+                 max_host_cpu_pct: float = _pl.MAX_HOST_CPU_PCT, policy=None,
                  layout=None, topk: int = 8):
         self.live = jax.tree.map(lambda x: np.array(x, np.float64), fleet)
         self.max_host_cpu_pct = max_host_cpu_pct
@@ -755,128 +743,48 @@ class FleetSubstrate:
         jobs = list(jobs) + [jobs[-1]] * (size - len(jobs))
         return jnp.stack([_pl.job_delta(j) for j in jobs])
 
-    def init_carry(self, params: dict):
-        if self.policy is not None and self.policy.embed_dim > 0:
-            return self.policy.carry_init(params)
-        return ()
+    init_carry = ClusterSubstrate.init_carry
 
     def make_scorer(self, fused) -> Callable:
-        """Same uniform ``(params, snap, deltas, carry, n_real) ->
-        (q, ok, carry)`` contract as ``ClusterSubstrate.make_scorer``.
+        """The same batch scorer and contract as
+        ``ClusterSubstrate.make_scorer``, over (B, 6) afterstate-delta rows.
 
         Fused-capable specs (and the default ``policy=None``) keep the fused
-        column kernel; other policy classes score the assembled (N, 6) rows
-        through ``PolicySpec.score_set``.  Sequence specs feed their encoder
-        the job's normalized demand delta (the first ``ENCODER_IN`` entries
-        of ``delta / FEATURE_SCALE`` — the job-stream analogue of
-        ``pod_workload_features``).
+        column kernel; other policy classes score the afterstate rows
+        (``placement.afterstate_rows``) through ``PolicySpec.score_set``.
+        Flat, the filter is ``placement.fleet_feasible``; with a ``layout``
+        each request is ``shard.fleet_topk``'s candidate merge.  Sequence
+        specs feed their encoder the job's normalized demand delta (the
+        first ``ENCODER_IN`` entries of ``delta / FEATURE_SCALE`` — the
+        job-stream analogue of ``pod_workload_features``).
         """
-        max_cpu = self.max_host_cpu_pct
-        policy = self.policy
-        if policy is not None and policy.fused_kernel:
-            policy = None          # "mlp": the column kernel IS its score_set
-
-        from repro.kernels import ops
-        from repro.sched.api import _fleet_mode
-
-        mode = _fleet_mode(fused)
-
-        if self.layout is not None:
-            from repro.core.policy import ENCODER_IN
-            from repro.sched import shard as _shard
-
-            layout, k = self.layout, self.topk
-
-            def shard_topk(params, snap, d, emb=None):
-                return _shard.fleet_topk(params, snap, None, layout, k=k,
-                                         fused=fused, policy=policy,
-                                         embed=emb, delta=d,
-                                         max_host_cpu_pct=max_cpu)
-
-            if policy is None or policy.embed_dim == 0:
-
-                @jax.jit
-                def score(params, snap, deltas, carry, n_real):
-                    cv, ci = jax.vmap(
-                        lambda d: shard_topk(params, snap, d))(deltas)
-                    return cv, ci, carry
-
-                return score
-
-            @jax.jit
-            def score(params, snap, deltas, carry, n_real):
-                def step(c, xs):
-                    d, is_real = xs
-                    wf = (d / kenv.FEATURE_SCALE)[:ENCODER_IN]
-                    c2, emb = policy.encode_step(params, c, wf)
-                    c2 = jax.tree.map(lambda a, b: jnp.where(is_real, a, b),
-                                      c2, c)
-                    return c2, shard_topk(params, snap, d, emb)
-
-                is_real = jnp.arange(deltas.shape[0]) < n_real
-                carry2, (cv, ci) = jax.lax.scan(step, carry, (deltas, is_real))
-                return cv, ci, carry2
-
-            return score
-
-        def feasible(snap, deltas):
-            return (
-                (snap.healthy > 0.5)[None, :]
-                & (snap.cpu_pct[None, :] + deltas[:, 0:1] <= max_cpu)
-                & (snap.mem_pct[None, :] + deltas[:, 1:2] <= 95.0)
-                & (snap.job_util_pct[None, :] + deltas[:, 2:3]
-                   <= 100.0 + 1e-6)
-            )
-
-        def afterstate_rows(snap, delta, embed=None):
-            feats = (jnp.stack(_pl.fleet_cols(snap), axis=-1)
-                     + delta[None, :]) / kenv.FEATURE_SCALE
-            if embed is not None:
-                feats = jnp.concatenate(
-                    [feats,
-                     jnp.broadcast_to(embed, feats.shape[:-1] + embed.shape)],
-                    axis=-1)
-            return feats
-
-        if policy is None:
-
-            @jax.jit
-            def score(params, snap, deltas, carry, n_real):
-                cols = _pl.fleet_cols(snap)
-                q = jax.vmap(lambda d: ops.sdqn_score_delta(
-                    cols, d, params, mode=mode))(deltas)
-                return q, feasible(snap, deltas), carry
-
-            return score
-
-        if policy.embed_dim == 0:
-
-            @jax.jit
-            def score(params, snap, deltas, carry, n_real):
-                q = jax.vmap(lambda d: policy.score_set(
-                    params, afterstate_rows(snap, d)))(deltas)
-                return q, feasible(snap, deltas), carry
-
-            return score
-
         from repro.core.policy import ENCODER_IN
 
-        @jax.jit
-        def score(params, snap, deltas, carry, n_real):
-            def step(c, xs):
-                d, is_real = xs
-                wf = (d / kenv.FEATURE_SCALE)[:ENCODER_IN]
-                c2, emb = policy.encode_step(params, c, wf)
-                c2 = jax.tree.map(lambda a, b: jnp.where(is_real, a, b),
-                                  c2, c)
-                return c2, policy.score_set(
-                    params, afterstate_rows(snap, d, embed=emb))
+        max_cpu, policy = self.max_host_cpu_pct, self.policy
 
-            is_real = jnp.arange(deltas.shape[0]) < n_real
-            carry2, q = jax.lax.scan(step, carry, (deltas, is_real))
-            return q, feasible(snap, deltas), carry2
+        def workload_features(d):
+            return (d / kenv.FEATURE_SCALE)[:ENCODER_IN]
 
-        return score
+        if self.layout is None:
+            from repro.sched.api import _fleet_scores
+
+            def per_request(params, snap, d, embed, s):
+                q = _fleet_scores(snap, d, params=params, fused=fused,
+                                  policy=policy, embed=embed)
+                return q, _pl.fleet_feasible(snap, d, max_cpu)
+
+            return _batch_scorer(per_request, workload_features, policy)
+
+        from repro.sched import shard as _shard
+
+        layout, k = self.layout, self.topk
+
+        def per_request(params, snap, d, embed, s):
+            return _shard.fleet_topk(params, snap, None, layout, k=k,
+                                     fused=fused, policy=policy, embed=embed,
+                                     delta=d, max_host_cpu_pct=max_cpu)
+
+        return _batch_scorer(per_request, workload_features, policy)
 
     def feasible_one(self, node: int, job: _pl.JobSpec) -> bool:
         lv = self.live

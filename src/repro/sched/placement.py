@@ -4,8 +4,8 @@ This is the framework-integration of the paper's technique: the same
 Q-network that schedules pods in the reproduction schedules *jobs* (training
 replicas, serving replicas, data workers) onto fleet hosts.  Host state maps
 onto the six Table-2 features 1:1; scoring runs through the fused Pallas
-kernel (``repro.kernels.ops.sdqn_score``) so a 10^5-host fleet is scored in
-one kernel launch.
+column kernel (``repro.kernels.ops.sdqn_score_delta``) so a 10^5-host fleet
+is scored in one kernel launch.
 """
 from __future__ import annotations
 
@@ -16,9 +16,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import env as kenv
+from repro.core import env as kenv, schedulers
 from repro.core.types import NO_PLACEMENT
-from repro.kernels import ops
 
 # Job-slot ceiling per host: the Table-2 "pod utilization" analogue for the
 # fleet.  ``job_util_pct`` advances by JOB_UTIL_DELTA_PCT per bound job, and
@@ -26,6 +25,9 @@ from repro.kernels import ops
 # feature stays consistent with ``num_jobs`` across a placement session.
 MAX_JOBS_PER_HOST = 25.0
 JOB_UTIL_DELTA_PCT = 100.0 / MAX_JOBS_PER_HOST
+
+# The filter's default ceiling on a host's post-placement CPU utilization.
+MAX_HOST_CPU_PCT = 88.0
 
 # select() sentinel: no feasible host, the job is not bound.  Re-export of
 # the unified ``core.types.NO_PLACEMENT`` constant (old spelling kept).
@@ -70,6 +72,37 @@ def job_delta(job: JobSpec) -> jnp.ndarray:
                       JOB_UTIL_DELTA_PCT, 0.0, 0.0, 1.0])
 
 
+def fleet_ceilings(max_host_cpu_pct: float) -> tuple:
+    """The filter's ceilings on post-placement cpu %, mem % and job-util %.
+    The job-util one keeps ``job_util_pct <= 100`` (the k8s max-pods
+    analogue), so the third feature stays in the trained range."""
+    return (max_host_cpu_pct, 95.0, 100.0 + 1e-6)
+
+
+def fleet_feasible(fleet: FleetState, delta: jnp.ndarray,
+                   max_host_cpu_pct: float = MAX_HOST_CPU_PCT) -> jnp.ndarray:
+    """(N,) the filtering phase: a host is feasible when it is healthy and
+    its cpu, mem and job-util columns plus the job's afterstate ``delta``
+    stay under ``fleet_ceilings``.  The host's bind-time re-validation is
+    its numpy twin (``daemon.FleetSubstrate.feasible_one``)."""
+    cpu, mem, util = fleet_ceilings(max_host_cpu_pct)
+    return ((fleet.healthy > 0.5)
+            & (fleet.cpu_pct + delta[0] <= cpu)
+            & (fleet.mem_pct + delta[1] <= mem)
+            & (fleet.job_util_pct + delta[2] <= util))
+
+
+def afterstate_rows(fleet: FleetState, delta: jnp.ndarray,
+                    embed=None) -> jnp.ndarray:
+    """(N, 6 [+ E]) normalized afterstate rows of placing the job whose
+    afterstate ``delta`` is given on each host, with a sequence policy's
+    ``embed`` appended: what the column kernel builds in-kernel, for the
+    policy classes that score the whole set (``PolicySpec.score_set``)."""
+    feats = (jnp.stack(fleet_cols(fleet), axis=-1)
+             + delta[None, :]) / kenv.FEATURE_SCALE
+    return schedulers.with_embedding(feats, embed)
+
+
 class PlacementEngine:
     """Scores afterstates with a trained SDQN and binds jobs to hosts.
 
@@ -79,25 +112,15 @@ class PlacementEngine:
     """
 
     def __init__(self, qparams: dict, consolidate: bool = False,
-                 max_host_cpu_pct: float = 88.0, use_kernel: Optional[bool] = None):
+                 max_host_cpu_pct: float = MAX_HOST_CPU_PCT,
+                 use_kernel: Optional[bool] = None):
         self.qparams = qparams
         self.consolidate = consolidate
         self.max_host_cpu_pct = max_host_cpu_pct
         self.use_kernel = use_kernel
 
-    def _score(self, feats: jnp.ndarray) -> jnp.ndarray:
-        mode = None if self.use_kernel is None else ("interpret" if self.use_kernel else "ref")
-        return ops.sdqn_score(kenv.normalize_features(feats), self.qparams, mode=mode)
-
     def feasible(self, fleet: FleetState, job: JobSpec) -> jnp.ndarray:
-        return (
-            (fleet.healthy > 0.5)
-            & (fleet.cpu_pct + job.cpu_pct_demand <= self.max_host_cpu_pct)
-            & (fleet.mem_pct + job.mem_pct_demand <= 95.0)
-            # job-slot ceiling: keeps job_util_pct <= 100 (the k8s max-pods
-            # analogue), so the third feature stays in the trained range
-            & (fleet.job_util_pct + JOB_UTIL_DELTA_PCT <= 100.0 + 1e-6)
-        )
+        return fleet_feasible(fleet, job_delta(job), self.max_host_cpu_pct)
 
     def select(self, fleet: FleetState, job: JobSpec) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """Pick the host for one job. Returns (host index, scores).

@@ -212,12 +212,12 @@ def cluster_topk(params: dict, state: ClusterState, pod, cfg, layout: FleetLayou
 
 def fleet_topk(params: dict, fleet: _pl.FleetState, job, layout: FleetLayout,
                *, k: int = 4, fused="auto", policy=None, embed=None,
-               heuristic: bool = False, max_host_cpu_pct: float = 88.0,
-               delta=None):
+               heuristic: bool = False,
+               max_host_cpu_pct: float = _pl.MAX_HOST_CPU_PCT, delta=None):
     """Two-stage feasible top-k over a FleetState fleet (job→host placement).
 
     Same contract as :func:`cluster_topk`; feasibility is
-    ``PlacementEngine.feasible`` (healthy + post-delta cpu/mem/job-util
+    ``placement.fleet_feasible`` (healthy + post-delta cpu/mem/job-util
     ceilings), run in-kernel on the fused path.  ``delta`` overrides
     ``job_delta(job)`` with a pre-packed (6,) afterstate delta row (the
     daemon's batched path, where ``job`` may be a tracer-free placeholder).
@@ -227,33 +227,22 @@ def fleet_topk(params: dict, fleet: _pl.FleetState, job, layout: FleetLayout,
     k = max(1, min(k, layout.shard_size))
     if delta is None:
         delta = _pl.job_delta(job)
-    ceilings = (max_host_cpu_pct, 95.0, 100.0 + 1e-6)
     ft = shard_fleet(fleet, layout)
     fused_path = not heuristic and (policy is None or policy.fused_kernel)
 
-    def feasible(sub, delta):
-        return ((sub.healthy > 0.5)
-                & (sub.cpu_pct + delta[0] <= ceilings[0])
-                & (sub.mem_pct + delta[1] <= ceilings[1])
-                & (sub.job_util_pct + delta[2] <= ceilings[2]))
-
     def one_shard(sub, params, delta, embed):
         if fused_path:
-            return ops.sdqn_topk_delta(_pl.fleet_cols(sub), delta, params,
-                                       k=k, mode=_fleet_mode(fused),
-                                       ceilings=ceilings)
+            return ops.sdqn_topk_delta(
+                _pl.fleet_cols(sub), delta, params, k=k,
+                mode=_fleet_mode(fused),
+                ceilings=_pl.fleet_ceilings(max_host_cpu_pct))
         if heuristic:
             q = heuristic_score(sub, job)
         else:
-            feats = (jnp.stack(_pl.fleet_cols(sub), axis=-1)
-                     + delta[None, :]) / kenv.FEATURE_SCALE
-            if embed is not None:
-                feats = jnp.concatenate(
-                    [feats,
-                     jnp.broadcast_to(embed, feats.shape[:-1] + embed.shape)],
-                    axis=-1)
-            q = policy.score_set(params, feats)
-        return jax.lax.top_k(jnp.where(feasible(sub, delta), q, -jnp.inf), k)
+            q = policy.score_set(params,
+                                 _pl.afterstate_rows(sub, delta, embed))
+        ok = _pl.fleet_feasible(sub, delta, max_host_cpu_pct)
+        return jax.lax.top_k(jnp.where(ok, q, -jnp.inf), k)
 
     vals, lidx = _over_shards(one_shard, layout, ft, params, delta, embed)
     return _merge(vals, _global_index(vals, lidx, layout))

@@ -1023,6 +1023,69 @@ def test_every_scorer_is_the_jit_score_module(make_sub):
     assert text.startswith("module @jit_score ")
 
 
+def _requests(sub):
+    """Three distinct requests for ``sub``, the last too large for some
+    targets, and the encoder input each one feeds a sequence policy."""
+    if isinstance(sub, ClusterSubstrate):
+        pod = kenv.default_pod(CFG)
+        reqs = [pod, pod._replace(cpu_request=jnp.float32(900.0),
+                                  mem_demand=jnp.float32(300.0)),
+                pod._replace(cpu_request=jnp.float32(3500.0))]
+        return reqs, [policy_mod.pod_workload_features(p) for p in reqs]
+    reqs = [placement.JobSpec(), placement.JobSpec(12.0, 3.0),
+            placement.JobSpec(84.6, 1.0)]
+    return reqs, [(placement.job_delta(j) / kenv.FEATURE_SCALE)
+                  [:policy_mod.ENCODER_IN] for j in reqs]
+
+
+@pytest.mark.parametrize("make_sub", [f for _, f in _scorer_variants()],
+                         ids=[n for n, _ in _scorer_variants()])
+def test_batch_scorer_matches_one_request_at_a_time(make_sub):
+    """Each real row of the one-launch batch scorer is what the
+    single-request dispatch gives that request: ``api.score`` and the
+    filter flat, ``api.topk`` with the substrate's layout and ``k``
+    sharded.  A sequence policy's carry advances over the real rows only,
+    in order, and each row scores with the embedding of its own step.
+    Scores agree to float32 rounding (a batched matmul may round one ulp
+    apart from a single one); filters and candidate indices exactly."""
+    sub = make_sub()
+    spec = sub.policy
+    params = (spec.init(jax.random.PRNGKey(0)) if spec is not None
+              else dqn.init_qnet(jax.random.PRNGKey(0)))
+    reqs, feats = _requests(sub)
+    carry0 = sub.init_carry(params)
+    a, b, carry = sub.make_scorer("auto")(
+        params, sub.snapshot(), sub.pack(reqs, len(reqs) + 1), carry0,
+        len(reqs))
+    fleet = jax.tree.map(jnp.asarray, sub.live)
+    if isinstance(sub, FleetSubstrate):
+        fleet = jax.tree.map(lambda x: x.astype(jnp.float32), fleet)
+    cfg = CFG if isinstance(sub, ClusterSubstrate) else None
+    c = carry0
+    for i, (req, wf) in enumerate(zip(reqs, feats)):
+        emb = None
+        if spec is not None and spec.embed_dim > 0:
+            c, emb = spec.encode_step(params, c, wf)
+        if sub.layout is None:
+            q = api.score(fleet, req, params=params, cfg=cfg, shard=False,
+                          policy=spec, embed=emb)
+            ok = (kenv.feasible(fleet, req, CFG) if cfg is not None else
+                  placement.PlacementEngine(params).feasible(fleet, req))
+            np.testing.assert_allclose(a[i], q, rtol=1e-5, atol=1e-6)
+            np.testing.assert_array_equal(b[i], ok)
+        else:
+            vals, idx = api.topk(fleet, req, params=params, cfg=cfg,
+                                 k=sub.topk, shard=sub.layout, policy=spec,
+                                 embed=emb)
+            np.testing.assert_allclose(a[i], vals, rtol=1e-5, atol=1e-6)
+            np.testing.assert_array_equal(b[i], idx)
+    assert not np.all(np.isfinite(a[2]) if sub.layout is not None
+                      else b[2])     # the large request is filtered somewhere
+    for got, want in zip(jax.tree.leaves(carry), jax.tree.leaves(c)):
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    assert jax.tree.structure(carry) == jax.tree.structure(c)
+
+
 class TestServeCheckpointLoading:
     def test_load_qnet_roundtrips_through_ckpt(self, tmp_path, qparams):
         from repro.checkpoint import ckpt

@@ -305,17 +305,18 @@ CPU_ONLY_CHIP_DIGESTS = {
     100000: "645694515f85a87a925e2219ceffaae71359dac74de182c2f2e26d8c2c6967bc",
 }
 
+# the same for the ``eks-100k-gpu`` cell's scorer: 100,000 nodes of 8
+# accelerators in 8 shards, batch 32, the top-k kernel over 16 columns
+ACCEL_CHIP_DIGEST = (
+    "7b2f5225b93c1a83bdd78d025fc350151f39b2f513f8d87ea863f3c71c21fb10")
 
-@pytest.mark.parametrize("n,shards", [(5000, None), (100000, 8)])
-def test_cpu_only_daemon_scorer_is_the_program_it_was(one_chip,
-                                                      pallas_default, n,
-                                                      shards):
+
+def _scorer_digest(one_chip, cfg, layout):
+    """sha256 of the daemon scorer's StableHLO at batch 32 on one chip."""
     import hashlib
 
     from jax._src import config as jax_config
 
-    cfg = fleet_cluster(n)
-    layout = plan_fleet_layout(n, shards=shards) if shards else None
     sub = ClusterSubstrate(kenv.reset(jax.random.PRNGKey(0), cfg), cfg,
                            layout=layout)
     pods = sub.pack([kenv.default_pod(cfg)], 32)
@@ -324,5 +325,20 @@ def test_cpu_only_daemon_scorer_is_the_program_it_was(one_chip,
             _shapes(PARAMS, one_chip), _shapes(sub.snapshot(), one_chip),
             _shapes(pods, one_chip), (),
             jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == \
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+@pytest.mark.parametrize("n,shards", [(5000, None), (100000, 8)])
+def test_cpu_only_daemon_scorer_is_the_program_it_was(one_chip,
+                                                      pallas_default, n,
+                                                      shards):
+    layout = plan_fleet_layout(n, shards=shards) if shards else None
+    assert _scorer_digest(one_chip, fleet_cluster(n), layout) == \
         CPU_ONLY_CHIP_DIGESTS[n]
+
+
+def test_accelerator_daemon_scorer_is_the_program_it_was(one_chip,
+                                                         pallas_default):
+    n = 100000
+    assert _scorer_digest(one_chip, _gpu_cluster(n),
+                          plan_fleet_layout(n, shards=8)) == ACCEL_CHIP_DIGEST
